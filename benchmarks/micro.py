@@ -127,6 +127,8 @@ import repro.models.openpose as op
 from repro.core.executor import DestinationExecutor
 from repro.core.library import make_openpose_library
 from repro.core.transport import TCPServer
+from repro.utils import enable_compile_cache
+enable_compile_cache()      # this process owns the accelerator
 net = op.OpenPoseLite()
 ex = DestinationExecutor({"openpose": make_openpose_library(net)},
                          name="bench-dest")
@@ -166,11 +168,15 @@ def _openpose_offload_walls(frames: int,
     OpenPose-lite frames over loopback TCP to a destination in its own
     process, model resident and jit warm in both cases.  (Co-locating the
     destination in this process makes "overlap" impossible — one GIL — and
-    was measured to invert the comparison.)"""
+    was measured to invert the comparison.)  This process is the host: it
+    pins its JAX to the CPU so the destination child gets the accelerator."""
     import repro.models.openpose as op
     from repro.core.executor import HostRuntime, PipelinedHostRuntime
     from repro.core.transport import TCPChannel
     from repro.models.params import init_params
+    from repro.utils import pin_host_cpu
+
+    pin_host_cpu()
 
     net = op.OpenPoseLite()
     params = init_params(op.op_param_specs(net), jax.random.PRNGKey(0),

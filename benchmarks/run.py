@@ -37,6 +37,12 @@ def main() -> None:
     emit_json = "--no-json" not in sys.argv
     rows = []
 
+    # every section here is a host-side (CPU) measurement; the one process
+    # that may take the accelerator is the OpenPose destination it spawns
+    from repro.utils import enable_compile_cache, pin_host_cpu
+    pin_host_cpu()
+    enable_compile_cache()
+
     from benchmarks import micro
 
     if smoke:

@@ -20,9 +20,11 @@ from repro.core.library import make_model_library
 from repro.core.virtualization import JETSON_TX2
 from repro.models import model as M
 from repro.serving.engine import generate_sequential
+from repro.utils import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = reduced(get_arch("granite-3-2b"))
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     lib = make_model_library(cfg, max_cache_len=32)

@@ -29,9 +29,11 @@ from repro.core.costmodel import Workload
 from repro.core.library import make_model_library
 from repro.core.transport import TCPServer
 from repro.core.virtualization import CLOUD_RTX, JETSON_TX2
+from repro.utils import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = reduced(get_arch("granite-3-2b"))
     from repro.models import model as M
     params = M.init_params(cfg, jax.random.PRNGKey(0))

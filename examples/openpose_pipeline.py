@@ -30,6 +30,7 @@ import repro.models.openpose as openpose
 from repro import avec
 from repro.configs.avec_openpose import WORKLOAD
 from repro.models.params import init_params
+from repro.utils import enable_compile_cache, pin_host_cpu
 
 from benchmarks.paper_tables import table4_speedup
 
@@ -48,6 +49,10 @@ def application(net, params, frames):
 
 
 def main() -> None:
+    # this process is the weak host: its JAX stays on the CPU, so the
+    # destination child below is the one process that takes the accelerator
+    pin_host_cpu()
+    enable_compile_cache()
     # destination node behind real TCP, in its OWN process — the paper's
     # topology (host and destination are different machines); weights arrive
     # over the wire via the send-once model cache

@@ -18,6 +18,7 @@ from repro.core.library import make_model_library
 from repro.data.pipeline import make_pipeline
 from repro.optim.optimizer import OptimizerConfig
 from repro.train.trainer import Trainer
+from repro.utils import enable_compile_cache
 
 
 def main() -> None:
@@ -25,6 +26,7 @@ def main() -> None:
     ap.add_argument("--arch", default="granite-3-2b", choices=list_archs())
     ap.add_argument("--steps", type=int, default=30)
     args = ap.parse_args()
+    enable_compile_cache()
 
     # reduced() preserves the family (GQA/MoE/SSD/hybrid/...) at CPU scale
     cfg = reduced(get_arch(args.arch))

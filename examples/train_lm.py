@@ -17,6 +17,7 @@ from repro.configs import get_arch
 from repro.data.pipeline import make_pipeline
 from repro.optim.optimizer import OptimizerConfig
 from repro.train.trainer import Trainer
+from repro.utils import enable_compile_cache
 
 
 def main() -> None:
@@ -27,6 +28,7 @@ def main() -> None:
     ap.add_argument("--vocab", type=int, default=4096)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = dataclasses.replace(
         get_arch("granite-3-2b"),

@@ -521,10 +521,16 @@ class DestinationExecutor:
     server-side (overriding frame-declared qos); ``tenant_max_inflight`` /
     ``tenant_max_bytes`` cap one tenant's concurrently admitted ``run``
     requests / payload bytes (0 = unlimited) — beyond the cap the tenant
-    gets a typed ``TenantThrottled`` response instead of a queue slot."""
+    gets a typed ``TenantThrottled`` response instead of a queue slot.
+
+    ``device`` is the accelerator this executor owns (default
+    ``jax.devices()[0]``): every weight, state and argument tree it receives
+    is placed there, so several executors in one process each keep their
+    sessions on their own chip."""
 
     def __init__(self, libraries: dict[str, dict[str, Callable]],
                  cache: ModelCache | None = None, name: str = "dest", *,
+                 device: jax.Device | None = None,
                  coalesce: bool = False,
                  coalesce_window_s: float | None = None,
                  max_coalesce: int | None = None,
@@ -536,6 +542,7 @@ class DestinationExecutor:
         self.libraries = libraries
         self.cache = cache or ModelCache()
         self.name = name
+        self.device = device if device is not None else jax.devices()[0]
         self.fail = False          # fault-injection switch (tests/migration)
         self.draining = False      # zero-downtime drain: stop admitting runs
         # set by launch.serve (or tests) when an SHM doorbell listens beside
@@ -769,7 +776,14 @@ class DestinationExecutor:
             # channel for a SharedMemoryChannel (repro.avec prefer_shm)
             "shm": ({"path": self.shm_address, "host": _gethostname()}
                     if self.shm_address else None),
+            "device": self.device_info(),
         }, None, "raw"
+
+    def device_info(self) -> dict:
+        """The owned device as JAX reports it (advertised in the ping
+        reply, so a host can tell which accelerator serves it)."""
+        return {"platform": self.device.platform,
+                "kind": self.device.device_kind, "id": self.device.id}
 
     def effective_config(self) -> dict:
         """Every registered knob's effective value at this destination,
@@ -807,7 +821,7 @@ class DestinationExecutor:
 
     def _op_put_model(self, meta, tree):
         t0 = time.perf_counter()
-        params = jax.tree_util.tree_map(jax.numpy.asarray, tree)
+        params = jax.device_put(tree, self.device)
         nbytes = sum(np.asarray(l).nbytes for l in jax.tree_util.tree_leaves(tree))
         self.cache.put(meta["fp"], {
             "lib": meta["lib"], "params": params, "state": {},
@@ -898,13 +912,13 @@ class DestinationExecutor:
 
     def _op_restore(self, meta, tree):
         entry = self.cache.get(meta["fp"])
-        entry["state"] = jax.tree_util.tree_map(jax.numpy.asarray, tree)
+        entry["state"] = jax.device_put(tree, self.device)
         return {"ok": True}, None, "raw"
 
     def _run_one(self, meta, tree) -> tuple[dict, Any]:
         entry = self.cache.get(meta["fp"])
         fn = self.libraries[entry["lib"]][meta["fn"]]
-        args = jax.tree_util.tree_map(jax.numpy.asarray, tree)
+        args = jax.device_put(tree, self.device)
         t0 = time.perf_counter()
         out = fn(entry["params"], entry["state"], args)
         out = jax.block_until_ready(out)

@@ -28,8 +28,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 
 # ---------------------------------------------------------------------------
 # Canonical leaf helpers (one implementation for wire codec + optimizer)
@@ -101,7 +99,7 @@ def quantize_int8(x, *, br: int = 256, interpret: bool = False):
                    pl.BlockSpec((br, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct(xf.shape, jnp.int8),
                    jax.ShapeDtypeStruct((xf.shape[0], 1), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(xf)
@@ -124,7 +122,7 @@ def dequantize_int8(q, scale, dtype=jnp.float32, *, br: int = 256,
                   pl.BlockSpec((br, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(qf.shape, dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(qf, sf)

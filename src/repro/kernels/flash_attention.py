@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -97,7 +96,7 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
             pltpu.VMEM((bq,), jnp.float32),       # l
             pltpu.VMEM((bq, D), jnp.float32),     # acc
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

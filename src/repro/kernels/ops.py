@@ -1,10 +1,12 @@
 """jit'd wrappers around the Pallas kernels.
 
 Each op accepts model-native layouts, rearranges to the kernel layout, and
-dispatches to the Pallas kernel (``impl="pallas"``, interpret-mode on
-non-TPU backends) or the pure-jnp oracle (``impl="ref"``).  The model code
-paths default to "ref" on this CPU container (Mosaic does not lower to the
-CPU backend); on TPU the default flips to the kernels.
+dispatches to the Pallas kernel (``impl="pallas"``) or the pure-jnp oracle
+(``impl="ref"``).  ``impl`` defaults to the kernels on a TPU backend and to
+the oracle elsewhere (Mosaic does not lower to the CPU backend).  A Pallas
+kernel runs in interpret mode only when the caller passes
+``interpret=True``: off the TPU, ``impl="pallas"`` without it fails to
+lower instead of silently running the interpreter.
 """
 from __future__ import annotations
 
@@ -28,26 +30,24 @@ def default_impl() -> str:
     return "pallas" if on_tpu() else "ref"
 
 
-def _interp() -> bool:
-    return not on_tpu()
-
-
 # ---------------------------------------------------------------------------
 
-def flash_attention(q, k, v, *, causal: bool = True, impl: str | None = None):
+def flash_attention(q, k, v, *, causal: bool = True, impl: str | None = None,
+                    interpret: bool = False):
     """Model layout q: (B,S,H,D), k/v: (B,T,K,D) -> (B,S,H,D)."""
     impl = impl or default_impl()
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     if impl == "pallas":
-        o = _fa_k(qt, kt, vt, causal=causal, interpret=_interp())
+        o = _fa_k(qt, kt, vt, causal=causal, interpret=interpret)
     else:
         o = _ref.flash_attention(qt, kt, vt, causal=causal)
     return o.transpose(0, 2, 1, 3)
 
 
-def decode_attention(q, k, v, kv_len, *, impl: str | None = None):
+def decode_attention(q, k, v, kv_len, *, impl: str | None = None,
+                     interpret: bool = False):
     """Model layout q: (B,1,H,D), k/v: (B,S,K,D), kv_len (B,) -> (B,1,H,D)."""
     impl = impl or default_impl()
     B, _, H, D = q.shape
@@ -57,13 +57,14 @@ def decode_attention(q, k, v, kv_len, *, impl: str | None = None):
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     if impl == "pallas":
-        o = _dec_k(qt, kt, vt, kv_len, interpret=_interp())
+        o = _dec_k(qt, kt, vt, kv_len, interpret=interpret)
     else:
         o = _ref.decode_attention(qt, kt, vt, kv_len)
     return o.reshape(B, H, D)[:, None]
 
 
-def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 256, impl: str | None = None):
+def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 256, impl: str | None = None,
+             interpret: bool = False):
     """Model layout x: (B,S,H,P), dt: (B,S,H), A: (H,), Bm/Cm: (B,S,G,N).
     Returns (y (B,S,H,P), final_state (B,H,P,N))."""
     impl = impl or default_impl()
@@ -80,31 +81,33 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 256, impl: str | None = None):
     Sp = S + pad
     nc = Sp // L
     xk = xf.reshape(B, nc, L, H, P).transpose(0, 3, 1, 2, 4)       # (B,H,nc,L,P)
-    dtk = dtf.reshape(B, nc, L, H).transpose(0, 3, 1, 2)            # (B,H,nc,L)
-    dak = dtk * A[None, :, None, None].astype(dtk.dtype)
+    dtk = dtf.reshape(B, nc, L, H).transpose(0, 3, 1, 2)[:, :, :, None]  # (B,H,nc,1,L)
+    dak = dtk * A[None, :, None, None, None].astype(dtk.dtype)
     Bk = Bf.reshape(B, nc, L, G, N).transpose(0, 3, 1, 2, 4)        # (B,G,nc,L,N)
     Ck = Cf.reshape(B, nc, L, G, N).transpose(0, 3, 1, 2, 4)
-    y, st = _ssd_k(xk, dtk, dak, Bk, Ck, chunk=L, interpret=_interp())
+    y, st = _ssd_k(xk, dtk, dak, Bk, Ck, chunk=L, interpret=interpret)
     y = y.transpose(0, 2, 3, 1, 4).reshape(B, Sp, H, P)[:, :S]
     return y, st
 
 
-def rmsnorm(x, scale, *, eps: float = 1e-6, impl: str | None = None):
+def rmsnorm(x, scale, *, eps: float = 1e-6, impl: str | None = None,
+            interpret: bool = False):
     impl = impl or default_impl()
     if impl == "pallas":
-        return _rms_k(x, scale, eps=eps, interpret=_interp())
+        return _rms_k(x, scale, eps=eps, interpret=interpret)
     return _ref.rmsnorm(x, scale, eps=eps)
 
 
-def quantize_int8(x, *, impl: str | None = None):
+def quantize_int8(x, *, impl: str | None = None, interpret: bool = False):
     impl = impl or default_impl()
     if impl == "pallas":
-        return _q_k(x, interpret=_interp())
+        return _q_k(x, interpret=interpret)
     return _ref.quantize_int8(x)
 
 
-def dequantize_int8(q, scale, dtype=jnp.float32, *, impl: str | None = None):
+def dequantize_int8(q, scale, dtype=jnp.float32, *, impl: str | None = None,
+                    interpret: bool = False):
     impl = impl or default_impl()
     if impl == "pallas":
-        return _deq_k(q, scale, dtype, interpret=_interp())
+        return _deq_k(q, scale, dtype, interpret=interpret)
     return _ref.dequantize_int8(q, scale, dtype)

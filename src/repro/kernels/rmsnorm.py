@@ -8,8 +8,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)                   # (br, D)
@@ -41,7 +39,7 @@ def rmsnorm(x, scale, *, br: int = 256, eps: float = 1e-6,
         ],
         out_specs=pl.BlockSpec((br, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(xf.shape, x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(xf, scale)

@@ -127,8 +127,6 @@ def _compile_cell(cfg, shape, mesh, profile):
 
 def _costs_of(compiled) -> dict:
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):    # older jax returns [dict]
-        ca = ca[0] if ca else {}
     coll, by_type = parse_collective_bytes(compiled.as_text())
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0)),

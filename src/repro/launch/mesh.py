@@ -10,23 +10,16 @@ Defined as functions so importing this module never touches jax device state
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType only exists in newer jax; older versions default to Auto
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
-
-
-def _axis_kwargs(n: int) -> dict:
-    return {"axis_types": (AxisType.Auto,) * n} if AxisType is not None else {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke paths (tests/benchmarks)."""
-    return jax.make_mesh((1, 1), ("data", "model"), **_axis_kwargs(2))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -2,6 +2,11 @@
 more destinations as an ``avec.connect`` host, or run the continuous-batching
 engine locally.
 
+Every role serves the architecture at its published widths; ``--reduced``
+swaps in the family-preserving miniature (CPU tests).  The destination owns
+the accelerator: it builds no weights (they arrive over the wire), while a
+host process pins its own JAX to the CPU so it never takes the chip.
+
   # destination node (the "edge/cloud GPU server"):
   PYTHONPATH=src python -m repro.launch.serve --role destination --port 9000
 
@@ -19,6 +24,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass
 
 import jax
 import numpy as np
@@ -34,6 +40,72 @@ from repro.obs import metrics as obs_metrics
 from repro.obs.config import global_config
 from repro.obs.trace import emit
 from repro.serving.engine import Request, ServingEngine
+from repro.utils import enable_compile_cache, pin_host_cpu
+
+
+@dataclass
+class ServedDestination:
+    """One destination as ``--role destination`` stands it up: the executor
+    plus the listeners in front of it."""
+    executor: DestinationExecutor
+    server: TCPServer | None = None
+    shm_server: SharedMemoryServer | None = None
+    metrics_server: obs_metrics.MetricsServer | None = None
+
+    @property
+    def address(self) -> str:
+        """``tcp://`` URL of the loopback TCP listener (for ``avec.connect``)."""
+        return f"tcp://127.0.0.1:{self.server.port}"
+
+    def stop(self) -> None:
+        if self.metrics_server is not None:
+            self.metrics_server.stop()
+        if self.shm_server is not None:
+            self.shm_server.stop()
+        if self.server is not None:
+            self.server.stop()
+        self.executor.shutdown()
+
+
+def start_destination(libraries: dict, *, name: str, device=None,
+                      port: int = 0, transport: str = "tcp",
+                      shm_path: str | None = None, coalesce: bool = False,
+                      tenant_weights: dict | None = None,
+                      tenant_max_inflight: int = 0,
+                      tenant_max_bytes: float = 0.0,
+                      metrics_port: int | None = None) -> ServedDestination:
+    """Build a :class:`DestinationExecutor` serving ``libraries`` on
+    ``device`` (default: the first JAX device) and start its listeners:
+    TCP and/or the shared-memory ring per ``transport``, plus the
+    Prometheus listener when the ``metrics_port`` knob resolves above 0."""
+    ex = DestinationExecutor(libraries, name=name, device=device,
+                             coalesce=coalesce,
+                             tenant_weights=tenant_weights or None,
+                             tenant_max_inflight=tenant_max_inflight,
+                             tenant_max_bytes=tenant_max_bytes)
+    dest = ServedDestination(ex)
+    if transport in ("tcp", "both"):
+        dest.server = TCPServer(ex.handle, port=port).start()
+        # the recv-pool lives on the server, not the executor — bind it
+        # into the executor's registry so one scrape covers the whole
+        # destination
+        obs_metrics.bind_server(ex.metrics, dest.server)
+    if transport in ("shm", "both"):
+        dest.shm_server = SharedMemoryServer(ex.handle, path=shm_path).start()
+        # advertised in every ping reply: same-host clients that dialed
+        # TCP see the doorbell and silently re-dial over the ring
+        ex.shm_address = dest.shm_server.address
+        obs_metrics.bind_pool_stats(ex.metrics, dest.shm_server.pool_stats,
+                                    pool="shm-server")
+        emit("shm_listening", path=dest.shm_server.address,
+             ring_bytes=dest.shm_server.ring_bytes)
+    mport = int(global_config().resolve("metrics_port", metrics_port))
+    if mport > 0:
+        dest.metrics_server = obs_metrics.MetricsServer(ex.metrics,
+                                                        port=mport).start()
+        emit("metrics_listening", port=dest.metrics_server.port,
+             url=f"http://127.0.0.1:{dest.metrics_server.port}/metrics")
+    return dest
 
 
 def main() -> None:
@@ -100,51 +172,34 @@ def main() -> None:
                          "metrics_port knob / AVEC_METRICS_PORT; 0 = off)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the family-preserving miniature of --arch "
+                         "instead of its published widths (CPU tests)")
     args = ap.parse_args()
 
-    cfg = reduced(get_arch(args.arch))
-    params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
+    cfg = get_arch(args.arch)
+    if args.reduced:
+        cfg = reduced(cfg)
 
     if args.role == "destination":
+        enable_compile_cache()
         lib = make_model_library(cfg, max_cache_len=args.max_len)
         weights = {}
         for part in args.tenant_weights.split(","):
             if part.strip():
                 tname, _, w = part.partition(":")
                 weights[tname.strip()] = float(w or 1.0)
-        ex = DestinationExecutor({"lm": lib}, name=f"{args.arch}-dest",
-                                 coalesce=args.coalesce,
-                                 tenant_weights=weights or None,
-                                 tenant_max_inflight=args.tenant_max_inflight,
-                                 tenant_max_bytes=args.tenant_max_bytes)
-        server = shm_server = None
-        if args.transport in ("tcp", "both"):
-            server = TCPServer(ex.handle, port=args.port).start()
-            # the recv-pool lives on the server, not the executor — bind it
-            # into the executor's registry so one scrape covers the whole
-            # destination
-            obs_metrics.bind_server(ex.metrics, server)
-        if args.transport in ("shm", "both"):
-            shm_server = SharedMemoryServer(ex.handle,
-                                            path=args.shm_path).start()
-            # advertised in every ping reply: same-host clients that dialed
-            # TCP see the doorbell and silently re-dial over the ring
-            ex.shm_address = shm_server.address
-            obs_metrics.bind_pool_stats(ex.metrics, shm_server.pool_stats,
-                                        pool="shm-server")
-            emit("shm_listening", path=shm_server.address,
-                 ring_bytes=shm_server.ring_bytes)
-        metrics_port = int(global_config().resolve("metrics_port",
-                                                   args.metrics_port))
-        msrv = None
-        if metrics_port > 0:
-            msrv = obs_metrics.MetricsServer(ex.metrics,
-                                             port=metrics_port).start()
-            emit("metrics_listening", port=msrv.port,
-                 url=f"http://127.0.0.1:{msrv.port}/metrics")
+        dest = start_destination(
+            {"lm": lib}, name=f"{args.arch}-dest", port=args.port,
+            transport=args.transport, shm_path=args.shm_path,
+            coalesce=args.coalesce, tenant_weights=weights,
+            tenant_max_inflight=args.tenant_max_inflight,
+            tenant_max_bytes=args.tenant_max_bytes,
+            metrics_port=args.metrics_port)
+        ex = dest.executor
         emit("destination_listening", arch=args.arch,
-             port=server.port if server is not None else None,
-             transport=args.transport,
+             port=dest.server.port if dest.server is not None else None,
+             transport=args.transport, device=ex.device_info(),
              coalesce=args.coalesce, tenant_weights=weights,
              tenant_max_inflight=args.tenant_max_inflight,
              tenant_max_bytes=args.tenant_max_bytes)
@@ -162,16 +217,12 @@ def main() -> None:
                 res = ex.drain(timeout_s=args.drain_timeout)
                 emit("drain_end", name=ex.name, drained=res["drained"],
                      pending=res["pending"], replay_hits=ex.replay_hits)
-            if msrv is not None:
-                msrv.stop()
-            if shm_server is not None:
-                shm_server.stop()
-            if server is not None:
-                server.stop()
-            ex.shutdown()
+            dest.stop()
         return
 
     if args.role == "host":
+        pin_host_cpu()
+        params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
         targets = [addr.strip() if addr.strip().startswith(("tcp://",
                                                             "shm://"))
                    else f"tcp://{addr.strip()}"
@@ -222,6 +273,8 @@ def main() -> None:
                          queue_depth=row.get("queue_depth", 0))
         return
 
+    enable_compile_cache()
+    params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
     eng = ServingEngine(cfg, params, max_batch=args.max_batch,
                         max_len=args.max_len)
     rng = np.random.default_rng(args.seed)

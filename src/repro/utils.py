@@ -3,12 +3,45 @@ from __future__ import annotations
 
 import hashlib
 import json
+import os
 import time
 from typing import Any, Iterable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+#: the checkout root (``src/repro/utils.py`` -> ``.``): a fixed path, so the
+#: compile cache keyed under it is found again by the next run
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache() -> str:
+    """Entry points call this once, before their first compile.  When
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already uses it and nothing is
+    set here; otherwise the persistent cache goes to ``<checkout>/.jax_cache``.
+    Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def pin_host_cpu() -> None:
+    """Keep this process's JAX on the CPU platform.  AVEC's host is the weak
+    device: a host process (or a parent that spawns a destination) must
+    never take the accelerator its destination needs.  Call before the
+    first JAX computation; raises if JAX already initialised another
+    platform, since the pin could no longer take effect."""
+    jax.config.update("jax_platforms", "cpu")
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"JAX already runs on {jax.default_backend()!r} in this process; "
+            "pin_host_cpu() must run before the first JAX call")
 
 
 def tree_bytes(tree: Any) -> int:

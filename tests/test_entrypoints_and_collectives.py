@@ -26,7 +26,7 @@ def test_serve_entrypoint_cli():
     env = dict(os.environ, PYTHONPATH=SRC)
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--role", "local",
-         "--requests", "2", "--max-len", "48"],
+         "--reduced", "--requests", "2", "--max-len", "48"],
         capture_output=True, text=True, timeout=600, env=env)
     assert out.returncode == 0, out.stderr[-1500:]
     # entrypoints log structured JSON (repro.obs.trace.emit), one per line
@@ -34,6 +34,37 @@ def test_serve_entrypoint_cli():
               if line.startswith("{")]
     done = [e for e in events if e["event"] == "engine_complete"]
     assert done and done[0]["tokens"] > 0 and done[0]["tok_per_s"] > 0
+
+
+CACHE_PROBE = r"""
+import sys
+sys.path.insert(0, sys.argv[1])
+import jax
+from repro.utils import enable_compile_cache, pin_host_cpu
+pin_host_cpu()
+print(enable_compile_cache())
+print(jax.config.jax_compilation_cache_dir)
+print(jax.default_backend())
+"""
+
+
+@pytest.mark.parametrize("env_dir", [None, "/some/cache/dir"])
+def test_compile_cache_location(env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set in code;
+    without it the cache sits at the fixed <checkout>/.jax_cache."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    out = subprocess.run([sys.executable, "-c", CACHE_PROBE, SRC],
+                         capture_output=True, text=True, timeout=120,
+                         env=env)
+    assert out.returncode == 0, out.stderr[-1500:]
+    used, configured, backend = out.stdout.split()
+    want = env_dir or os.path.join(os.path.abspath(
+        os.path.join(SRC, "..")), ".jax_cache")
+    assert used == configured == want
+    assert backend == "cpu"
 
 
 def test_dcn_wire_accounting():
@@ -48,10 +79,7 @@ def test_dcn_wire_accounting():
 
 def test_compressed_psum_single_axis():
     """compressed_psum == psum(quant-dequant) numerics on a 1-device mesh."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        pytest.skip("jax.sharding.AxisType not in this jax version")
+    from jax.sharding import AxisType
     from repro.optim.compression import compressed_psum
     mesh = jax.make_mesh((1,), ("pod",), axis_types=(AxisType.Auto,))
     x = jax.random.normal(jax.random.PRNGKey(0), (16, 32))

@@ -96,7 +96,8 @@ def test_ssd_scan_kernel_vs_sequential(B, S, H, P, G, N, L):
     Bm = jax.random.normal(ks[3], (B, S, G, N)) * 0.3
     Cm = jax.random.normal(ks[4], (B, S, G, N)) * 0.3
     y_ref, st_ref = ssd_sequential(x, dt, A, Bm, Cm)
-    y_k, st_k = ops.ssd_scan(x, dt, A, Bm, Cm, chunk=L, impl="pallas")
+    y_k, st_k = ops.ssd_scan(x, dt, A, Bm, Cm, chunk=L, impl="pallas",
+                              interpret=True)
     scale = float(jnp.max(jnp.abs(y_ref))) + 1.0
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_ref),
                                atol=2e-4 * scale)
@@ -126,7 +127,7 @@ def test_ssd_chunked_matches_sequential_jnp():
 def test_rmsnorm_kernel(n, d):
     x = jax.random.normal(jax.random.PRNGKey(6), (n, d))
     s = jax.random.normal(jax.random.PRNGKey(7), (d,))
-    out = ops.rmsnorm(x, s, impl="pallas")
+    out = ops.rmsnorm(x, s, impl="pallas", interpret=True)
     want = ref.rmsnorm(x, s)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
 
@@ -134,11 +135,11 @@ def test_rmsnorm_kernel(n, d):
 @pytest.mark.parametrize("n,d", [(64, 256), (100, 128), (3, 512)])
 def test_comm_quant_kernel(n, d):
     x = jax.random.normal(jax.random.PRNGKey(8), (n, d))
-    q1, s1 = ops.quantize_int8(x, impl="pallas")
+    q1, s1 = ops.quantize_int8(x, impl="pallas", interpret=True)
     q2, s2 = ref.quantize_int8(x)
     assert bool(jnp.all(q1 == q2))
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), rtol=1e-6)
-    deq = ops.dequantize_int8(q1, s1, impl="pallas")
+    deq = ops.dequantize_int8(q1, s1, impl="pallas", interpret=True)
     # per-row error bound: scale/2 = absmax/254
     err = jnp.max(jnp.abs(deq - x), axis=-1)
     bound = jnp.max(jnp.abs(x), axis=-1) / 127.0

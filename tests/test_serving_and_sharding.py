@@ -114,10 +114,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, json, dataclasses
 sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp
-try:
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 from repro.configs import get_arch, reduced, SHAPES
 from repro.distributed import sharding as sh
 from repro.launch.dryrun import build_cell
@@ -127,14 +124,11 @@ cfg = dataclasses.replace(reduced(get_arch(sys.argv[2])),
                           num_heads=4, num_kv_heads=4, unroll_blocks=True)
 shape = dataclasses.replace(SHAPES["train_4k"], seq_len=64, global_batch=8)
 mesh = jax.make_mesh((4, 2), ("data", "model"),
-                     **({"axis_types": (AxisType.Auto,) * 2}
-                        if AxisType is not None else {}))
+                     axis_types=(AxisType.Auto,) * 2)
 fn, args, in_sh, out_sh = build_cell(cfg, shape, mesh, "dp_tp")
 with mesh:
     compiled = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh).lower(*args).compile()
 ca = compiled.cost_analysis()
-if isinstance(ca, list):
-    ca = ca[0] if ca else {}
 coll, by_type = parse_collective_bytes(compiled.as_text())
 print(json.dumps({"flops": float(ca.get("flops", 0)), "coll": coll,
                   "ops": sorted(by_type)}))
