@@ -50,6 +50,7 @@ import numpy as np  # noqa: E402
 from repro import avec  # noqa: E402
 from repro.configs import get_arch  # noqa: E402
 from repro.configs.avec_openpose import WORKLOAD  # noqa: E402
+from repro.core.executor import COMPILES  # noqa: E402
 from repro.core.library import make_model_library, make_openpose_library  # noqa: E402
 from repro.launch.serve import start_destination  # noqa: E402
 from repro.models import model as M  # noqa: E402
@@ -94,31 +95,6 @@ def _host_np(tree):
 def _max_rel_err(a, ref) -> float:
     return float(np.max(np.abs(np.asarray(a, np.float64) - ref))
                  / max(float(np.max(np.abs(ref))), 1e-30))
-
-
-class CompileWatch:
-    """Backend compile seconds and persistent-cache hits, from JAX's own
-    monitoring events."""
-
-    def __init__(self) -> None:
-        self.compile_s = 0.0
-        self.compiles = 0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, duration: float, **_kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += duration
-            self.compiles += 1
-
-    def _event(self, event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def line(self) -> str:
-        return (f"compiles={self.compiles} compile_s={self.compile_s:.3f} "
-                f"persistent_cache_hits={self.cache_hits}")
 
 
 # ---------------------------------------------------------------------------
@@ -360,7 +336,7 @@ def main(argv=None) -> int:
     if plats and "cpu" not in plats.split(","):
         jax.config.update("jax_platforms", f"{plats},cpu")
     log(f"[cache] compile cache: {enable_compile_cache()}")
-    watch = CompileWatch()
+    compiles0, compile_s0 = COMPILES.count, COMPILES.seconds
     want = 4 if args.four_chips else 1
     dev = device_phase(want)
     host = jax.devices("cpu")[0]
@@ -386,7 +362,9 @@ def main(argv=None) -> int:
                               "full-width weights are not on the chip")
         finally:
             dest.stop()
-    log(f"[compile] {watch.line()}; phases {time.perf_counter() - t0:.3f}s "
+    log(f"[compile] compiles={COMPILES.count - compiles0} "
+        f"compile_s={COMPILES.seconds - compile_s0:.3f}; "
+        f"phases {time.perf_counter() - t0:.3f}s "
         f"(one run, not a benchmark)")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -753,8 +753,11 @@ class ClientSession(AvecSession):
             if planned is not None:
                 return self._call_sharded(fn, args, *planned)
         cid = f"{self._call_ns}-{next(self._call_n)}"
+        trace = _trace.start_trace(fn=fn, call_id=cid)
+        if trace is not None:
+            trace.mark("client.call")
         try:
-            out = self._tracked_call(fn, args, cid)
+            out = self._tracked_call(fn, args, cid, trace)
         except TenantThrottled:
             try:
                 self.client.refresh_capabilities(self.destination)
@@ -763,11 +766,15 @@ class ClientSession(AvecSession):
             raise
         except DestinationDraining as e:    # before _FAILOVER_EXC: subclass
             self._rehome_for_drain(e)
-            out = self._tracked_call(fn, args, cid)
+            if trace is not None:
+                trace = trace.retry()
+            out = self._tracked_call(fn, args, cid, trace)
         except self._FAILOVER_EXC as e:
             if not self._recover_same_destination():
                 self._failover_or_raise(e)
-            out = self._tracked_call(fn, args, cid)
+            if trace is not None:
+                trace = trace.retry()
+            out = self._tracked_call(fn, args, cid, trace)
         self._steps += 1
         if self._shadow is not None:
             try:
@@ -781,10 +788,13 @@ class ClientSession(AvecSession):
                                             self._steps)
             except self._FAILOVER_EXC:
                 pass            # shadow is best-effort; keep the last one
+        if trace is not None:
+            trace.mark("client.return")
+            _trace.finish_trace(trace, trace.wall_s)
         return out
 
-    def _tracked_call(self, fn: str, args: Any,
-                      call_id: Optional[str] = None) -> Any:
+    def _tracked_call(self, fn: str, args: Any, call_id: Optional[str],
+                      trace) -> Any:
         """One cycle with the registry's live-load counter held, so the
         scheduler's queueing (and coalescer-amortization) terms see real
         in-flight pressure from facade traffic."""
@@ -792,7 +802,7 @@ class ClientSession(AvecSession):
         dest = self.destination
         reg.acquire(dest)
         try:
-            return super().call(fn, args, call_id=call_id)
+            return self._cycle(fn, args, call_id, trace)
         finally:
             reg.release(dest)
 
@@ -880,6 +890,8 @@ class ClientSession(AvecSession):
         sums to its wall like an unsharded one."""
         cid = f"{self._call_ns}-{next(self._call_n)}"
         parent = _trace.start_trace(fn=fn, call_id=cid)
+        if parent is not None:
+            parent.mark("client.call")
         t0 = time.perf_counter()
         parts = plan.split(args)
         n = plan.n_shards
@@ -997,6 +1009,8 @@ class ClientSession(AvecSession):
             if parent is not None:
                 parent.add("stitch", stitch_s)
             wall = time.perf_counter() - t0
+            if parent is not None:
+                parent.mark("client.return")
             _trace.finish_trace(parent, wall)
             compute = max(computes) if computes else 0.0
             self.profiler.record_cycle(

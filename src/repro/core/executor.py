@@ -11,6 +11,9 @@ out-of-order completions):
   {"op": "run", "fp", "fn": name, "codec",
    "batchable": bool}                     + inputs tree
        -> {"ok": True, "compute_s": float, "coalesced": int} + outputs tree
+          (a traced run, meta "trace" set, also answers "trace", "spans",
+          "children", "marks" and, if its dispatch compiled, "compiles":
+          see repro.obs.trace)
   {"op": "drop_session", "fp"}            -> {"ok": True}
   {"op": "snapshot", "fp"}                -> session state tree (migration)
   {"op": "restore", "fp"}  + state tree   -> {"ok": True}
@@ -195,6 +198,81 @@ def _throttle_backoff(attempt: int, retry_after_s: float) -> float:
     synchronized retries would just collide at the admission gate again."""
     base = min(max(retry_after_s, 1e-3) * (2 ** attempt), 0.5)
     return base * random.uniform(0.5, 1.5)
+
+
+# ---------------------------------------------------------------------------
+# Destination-side observability: compile counter, per-call trace
+# ---------------------------------------------------------------------------
+
+class _CompileCounter:
+    """Backend compiles in this process and their seconds, from JAX's
+    monitoring events (one listener for the process, whatever thread
+    compiles)."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self) -> None:
+        self._lock = _sanitize.make_lock("_CompileCounter._lock")
+        self._count = 0         # guarded-by: _lock
+        self._seconds = 0.0     # guarded-by: _lock
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+
+    def _on_event(self, event: str, duration: float, **_kw) -> None:
+        if event == self.EVENT:
+            with self._lock:
+                self._count += 1
+                self._seconds += duration
+
+    @property
+    def count(self) -> int:
+        with self._lock:
+            return self._count
+
+    @property
+    def seconds(self) -> float:
+        with self._lock:
+            return self._seconds
+
+
+COMPILES = _CompileCounter()
+
+
+class _CallTrace:
+    """The destination's half of one traced call: its span children, its
+    ``dest.*`` marks and compile count (all returned in the response meta)
+    and the profiler annotation ``avec.call:<fn>`` around its residence.
+    The annotation opens once the unpacked meta shows a trace id, so it
+    starts ``queue.unpack`` after ``dest.in``."""
+
+    __slots__ = ("trace_id", "children", "marks", "compiles", "_ann")
+
+    def __init__(self, trace_id: str, fn: str, t_in_ns: int) -> None:
+        self.trace_id = trace_id
+        self.children: dict[str, float] = {}
+        self.marks = {"dest.in": t_in_ns}
+        self.compiles = 0
+        self.child("queue.unpack", (time.time_ns() - t_in_ns) * 1e-9)
+        self._ann = jax.profiler.TraceAnnotation(f"avec.call:{fn}")
+        self._ann.__enter__()
+
+    def child(self, name: str, dur_s: float) -> None:
+        self.children[name] = self.children.get(name, 0.0) + dur_s
+
+    def take(self, parts: dict, n: int = 1) -> None:
+        """Book a run's timed parts (``DestinationExecutor._run_timed``);
+        a call coalesced with ``n - 1`` others takes ``1/n`` of each."""
+        for name, value in parts.items():
+            if name == "compiles":
+                self.compiles += value
+            else:
+                self.child(name, value / n)
+
+    def close(self) -> None:
+        """``dest.out``: the response meta is final; the pack follows."""
+        if self._ann is not None:
+            self.marks["dest.out"] = time.time_ns()
+            self._ann.__exit__(None, None, None)
+            self._ann = None
 
 
 # ---------------------------------------------------------------------------
@@ -578,6 +656,14 @@ class DestinationExecutor:
         self.metrics = _obs_metrics.MetricsRegistry()
         _obs_metrics.bind_executor(self.metrics, self)
         _obs_metrics.bind_sanitizer(self.metrics)
+        self.metrics.counter(
+            "avec_backend_compiles_total",
+            "Backend (XLA) compiles in the destination's process."
+        ).bind(lambda: float(COMPILES.count))
+        self.metrics.counter(
+            "avec_backend_compile_seconds_total",
+            "Seconds spent in backend compiles in the destination's process."
+        ).bind(lambda: COMPILES.seconds)
 
     @property
     def coalesce_stats(self) -> dict:
@@ -709,13 +795,22 @@ class DestinationExecutor:
         # response is written); ops that must keep the frame's bytes alive
         # past this call — the coalescer's queue — retain it from here
         self._tls.lease = raw if isinstance(raw, BufferLease) else None
-        self._tls.t_in = time.monotonic()   # traced requests' queue span t0
+        t_in = time.time_ns()       # traced requests' dest.in mark
+        ct = None
         try:
             meta, tree = unpack_message(raw)
+            trace_id = meta.get("trace")
+            if trace_id is not None:
+                # the call's destination record takes its id's place in
+                # the meta, where the ops and the coalescer stamp it
+                ct = meta["trace"] = _CallTrace(
+                    trace_id, meta.get("fn", meta.get("op")), t_in)
             if self.fail:
                 raise RuntimeError(f"executor {self.name} marked failed")
             op = meta["op"]
             rmeta, rtree, codec = getattr(self, f"_op_{op}")(meta, tree)
+            if ct is not None:
+                ct.close()
             return pack_message(rmeta, rtree, codec=codec, request_id=rid)
         except Exception as e:  # noqa: BLE001 — protocol boundary
             return pack_message({"ok": False, "error": str(e),
@@ -724,6 +819,8 @@ class DestinationExecutor:
                                 request_id=rid)
         finally:
             self._tls.lease = None
+            if ct is not None:
+                ct.close()
 
     # ------------------------------------------------------------------
     def _op_ping(self, meta, tree):
@@ -830,6 +927,9 @@ class DestinationExecutor:
         return {"ok": True, "transfer_s": time.perf_counter() - t0}, None, "raw"
 
     def _op_run(self, meta, tree):
+        ct = meta.get("trace")      # the call's _CallTrace when traced
+        if ct is not None:
+            t_admit = time.perf_counter()
         codec = meta.get("codec", "raw")
         if isinstance(codec, list):
             # negotiated codec preference list (msgpack round-trips tuples
@@ -863,40 +963,51 @@ class DestinationExecutor:
                              f"~{retry_after * 1e3:.0f}ms"}, None, "raw"
         done_ok = False
         try:
-            t_exec0 = time.monotonic()
+            if ct is not None:
+                ct.child("queue.admit", time.perf_counter() - t_admit)
+                t_exec0 = time.time_ns()
             if self._coalescer is not None and meta.get("batchable"):
                 key = (meta["fp"], meta["fn"], codec, _batch_signature(tree))
                 rmeta, out_np = self._coalescer.submit(
                     key, meta, tree, lease=getattr(self._tls, "lease", None))
-            else:
+            elif ct is None:
                 rmeta, out_np = self._run_one(meta, tree)
+            else:
+                rmeta, out_np, parts = self._run_timed(meta, tree)
+                ct.take(parts)
             done_ok = True
             if call_id is not None:
                 # cache BEFORE span stamping: a replayed response must not
                 # carry the original execution's (stale) hop timings
                 self._replay_put(meta["fp"], call_id, rmeta, out_np)
-            if meta.get("trace") is not None:
-                rmeta = self._stamp_spans(dict(rmeta), meta["trace"],
-                                          t_exec0)
+            if ct is not None:
+                rmeta = self._stamp_spans(dict(rmeta), ct, t_exec0)
             return rmeta, out_np, codec
         finally:
             self._release(tenant, nbytes, served=done_ok)
 
-    def _stamp_spans(self, rmeta: dict, trace_id, t_exec0: float) -> dict:
-        """Attach destination hop spans to a traced run response: the
-        coalescer booked queue/coalesce waits into the rmeta; the direct
-        path's queue span is frame-arrival -> execution start."""
+    @staticmethod
+    def _stamp_spans(rmeta: dict, ct: _CallTrace, t_exec0_ns: int) -> dict:
+        """Attach destination hop spans, their children and the call's
+        marks to a traced run response: the coalescer booked queue/coalesce
+        waits into the rmeta; the direct path's queue span is
+        frame-arrival (``dest.in``) -> execution start."""
         spans = {}
         if "queue_s" in rmeta:
             spans["queue"] = rmeta.pop("queue_s")
             spans["coalesce"] = rmeta.pop("coalesce_s", 0.0)
+            # unpack and admission came before the enqueue, outside queue
+            ct.children.pop("queue.unpack", None)
+            ct.children.pop("queue.admit", None)
         else:
-            t_in = getattr(self._tls, "t_in", None)
-            spans["queue"] = (max(t_exec0 - t_in, 0.0)
-                              if t_in is not None else 0.0)
+            spans["queue"] = max(t_exec0_ns - ct.marks["dest.in"], 0) * 1e-9
         spans["execute"] = float(rmeta.get("compute_s", 0.0))
-        rmeta["trace"] = trace_id
+        rmeta["trace"] = ct.trace_id
         rmeta["spans"] = spans
+        rmeta["children"] = ct.children
+        rmeta["marks"] = ct.marks        # handle() adds dest.out before pack
+        if ct.compiles:
+            rmeta["compiles"] = ct.compiles
         return rmeta
 
     def _op_drop_session(self, meta, tree):
@@ -926,11 +1037,53 @@ class DestinationExecutor:
         out_np = jax.tree_util.tree_map(np.asarray, out)
         return {"ok": True, "compute_s": compute_s, "coalesced": 1}, out_np
 
+    def _run_timed(self, meta, tree) -> tuple[dict, Any, dict]:
+        """:meth:`_run_one` with each part timed and wrapped in a profiler
+        annotation ``avec.<child>``; ``execute`` keeps its extent (library
+        call through ``block_until_ready``).  Also returns the parts in
+        seconds — the ``respond.h2d``, ``execute.dispatch``,
+        ``execute.device`` and ``respond.d2h`` children — and the
+        dispatch's backend ``compiles``."""
+        entry = self.cache.get(meta["fp"])
+        fn = self.libraries[entry["lib"]][meta["fn"]]
+        annotate = jax.profiler.TraceAnnotation
+        t_h2d = time.perf_counter()
+        with annotate("avec.respond.h2d"):
+            args = jax.device_put(tree, self.device)
+        compiles0 = COMPILES.count
+        t0 = time.perf_counter()
+        with annotate("avec.execute.dispatch"):
+            out = fn(entry["params"], entry["state"], args)
+        t_disp = time.perf_counter()
+        with annotate("avec.execute.device"):
+            out = jax.block_until_ready(out)
+        t_dev = time.perf_counter()
+        compiles = COMPILES.count - compiles0
+        with annotate("avec.respond.d2h"):
+            out_np = jax.tree_util.tree_map(np.asarray, out)
+        parts = {"respond.h2d": t0 - t_h2d,
+                 "execute.dispatch": t_disp - t0,
+                 "execute.device": t_dev - t_disp,
+                 "respond.d2h": time.perf_counter() - t_dev,
+                 "compiles": compiles}
+        return ({"ok": True, "compute_s": t_dev - t0, "coalesced": 1},
+                out_np, parts)
+
+    def _run_alone(self, meta, tree) -> tuple[dict, Any]:
+        """One coalescer request run by itself; a traced one takes its
+        parts."""
+        ct = meta.get("trace")
+        if ct is None:
+            return self._run_one(meta, tree)
+        rmeta, out_np, parts = self._run_timed(meta, tree)
+        ct.take(parts)
+        return rmeta, out_np
+
     def _run_batch(self, key, metas: list, trees: list) -> list:
         """One stacked dispatch for a coalesced batch (leaves concatenated on
         axis 0), outputs split back by per-request row counts."""
         if len(trees) == 1:
-            return [self._run_one(metas[0], trees[0])]
+            return [self._run_alone(metas[0], trees[0])]
         rows = [np.asarray(jax.tree_util.tree_leaves(t)[0]).shape[0]
                 for t in trees]
         # every input leaf must carry its request's batch dim on axis 0 —
@@ -940,12 +1093,18 @@ class DestinationExecutor:
             for leaf in jax.tree_util.tree_leaves(t):
                 a = np.asarray(leaf)
                 if a.ndim == 0 or a.shape[0] != r:
-                    return [self._run_one(m, tr)
+                    return [self._run_alone(m, tr)
                             for m, tr in zip(metas, trees)]
         stacked = jax.tree_util.tree_map(
             lambda *xs: np.concatenate([np.asarray(x) for x in xs], axis=0),
             *trees)
-        rmeta, out_np = self._run_one(metas[0], stacked)
+        parts = None
+        for m in metas:
+            if m.get("trace") is not None:
+                rmeta, out_np, parts = self._run_timed(metas[0], stacked)
+                break
+        else:
+            rmeta, out_np = self._run_one(metas[0], stacked)
         total = int(sum(rows))
         out_leaves_chk = jax.tree_util.tree_leaves(out_np)
         if any(np.asarray(l).ndim == 0 or np.asarray(l).shape[0] != total
@@ -953,7 +1112,12 @@ class DestinationExecutor:
             # fn emits aggregate leaves (not row-aligned with the batch):
             # splitting would silently hand clients wrong slices — run each
             # request individually instead
-            return [self._run_one(m, t) for m, t in zip(metas, trees)]
+            return [self._run_alone(m, t) for m, t in zip(metas, trees)]
+        if parts is not None:
+            # each traced call's share, as compute_s divides below
+            for m in metas:
+                if m.get("trace") is not None:
+                    m["trace"].take(parts, len(trees))
         splits = np.cumsum(rows)[:-1]
         # flatten/unflatten explicitly: a tree_map-over-parts split would
         # misfire on output trees that contain list nodes of their own
@@ -1003,11 +1167,16 @@ class HostRuntime:
             t0 = time.perf_counter()
             req = pack_message(meta, tree, codec=codec)
             trace.add("serialize", time.perf_counter() - t0)
+            # one Channel.request writes and reads: "sent" is the hand-off
+            trace.mark("client.sent")
         else:
             req = pack_message(meta, tree, codec=codec)
         self.bytes_sent += len(req)
         resp = self.channel.request(req, timeout=self.timeout)
         self.bytes_received += len(resp)
+        if trace is not None:
+            trace.mark("client.recv")
+            t_unpack = time.perf_counter()
         try:
             rmeta, rtree = unpack_message(resp, copy=self.copy_results)
         finally:
@@ -1016,7 +1185,9 @@ class HostRuntime:
             # the slab recycles immediately)
             release_buffer(resp)
         if trace is not None:
-            trace.merge(rmeta.get("spans"))
+            trace.add("respond.unpack", time.perf_counter() - t_unpack,
+                      parent="respond")
+            trace.merge_response(rmeta)
         if not rmeta.get("ok", False):
             raise _remote_exception(rmeta)
         return rmeta, rtree
@@ -1280,6 +1451,7 @@ class PipelinedHostRuntime(HostRuntime):
                 # includes backpressure stalls (pumped receives) — the
                 # honest cost of getting this frame onto the wire
                 trace.add("send", time.perf_counter() - t_send)
+                trace.mark("client.sent")
             with self._cv:
                 self.bytes_sent += len(req)
                 if quant_saved >= 0:
@@ -1498,6 +1670,9 @@ class PipelinedHostRuntime(HostRuntime):
                 self._requests_completed += 1
         if fut is None:
             return
+        if trace is not None:
+            trace.mark("client.recv")
+            t_unpack = time.perf_counter()
         try:
             rmeta, rtree = unpack_message(data, copy=self.copy_results)
         except Exception as e:  # noqa: BLE001
@@ -1506,7 +1681,9 @@ class PipelinedHostRuntime(HostRuntime):
         if trace is not None:
             # safe without the future's result: the caller only reads the
             # trace after the future resolves (the future is the fence)
-            trace.merge(rmeta.get("spans"))
+            trace.add("respond.unpack", time.perf_counter() - t_unpack,
+                      parent="respond")
+            trace.merge_response(rmeta)
         if (self.adaptive_window and track is not None
                 and rmeta.get("ok", False) and "compute_s" in rmeta):
             t0, depth = track

@@ -174,19 +174,32 @@ class AvecSession:
 
     # ------------------------------------------------------------------
     def call(self, fn: str, args: Any, *, call_id: str | None = None) -> Any:
+        # facade trace entry point: mint the request-scoped trace id here;
+        # the runtime carries it in frame meta and every hop stamps a span
+        trace = _trace.start_trace(fn=fn, call_id=call_id)
+        if trace is None:
+            return self._cycle(fn, args, call_id, None)
+        trace.mark("client.call")
+        out = self._cycle(fn, args, call_id, trace)
+        trace.mark("client.return")
+        _trace.finish_trace(trace, trace.wall_s)
+        return out
+
+    def _cycle(self, fn: str, args: Any, call_id: str | None, trace) -> Any:
+        """One profiled round trip through the runtime; a traced call's
+        runtime wall lands in ``trace.wall_s`` for the caller to finish
+        the record with."""
         if not self._ready:
             self.ensure_model()
         sent0 = self.runtime.bytes_sent
         recv0 = self.runtime.bytes_received
-        # facade trace entry point: mint the request-scoped trace id here;
-        # the runtime carries it in frame meta and every hop stamps a span
-        trace = _trace.start_trace(fn=fn, call_id=call_id)
         t0 = time.perf_counter()
         out = self.runtime.run(self.fp, fn, args,
                                tenant=self.tenant, qos=self.qos,
                                call_id=call_id, trace=trace)
         wall = time.perf_counter() - t0
-        _trace.finish_trace(trace, wall)
+        if trace is not None:
+            trace.wall_s = wall
         compute = self.runtime.last_compute_s
         self.profiler.record_cycle(
             gpu_s=compute,

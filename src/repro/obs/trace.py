@@ -6,20 +6,51 @@ with one span per hop:
 
 * ``serialize`` — client-side pack into the vectored wire format;
 * ``send`` — client-side wire write (including backpressure stalls);
-* ``queue`` — destination-side wait from frame arrival to dispatch
-  pick (admission + DRR drain wait);
+* ``queue`` — destination-side time from frame arrival to execution
+  start: on the direct path the request's unpack and admission, on the
+  coalesced path the wait from enqueue to the DRR pick;
 * ``coalesce`` — destination-side window-fill wait inside a coalesced
   batch (absent on the direct path);
-* ``execute`` — destination compute (jit dispatch + block_until_ready);
-* ``respond`` — everything left of the end-to-end wall: response pack,
-  both wire flights, and client unpack (computed as the remainder at
-  :meth:`TraceRecord.finish`, so spans always sum to the wall).
+* ``execute`` — destination compute, host-timed: the library call (its
+  Python and the jit dispatch) and the wait for the device
+  (``block_until_ready``);
+* ``respond`` — everything left of the end-to-end wall: the argument
+  ``device_put``, the device-to-host copy, the response pack, both wire
+  flights and the client's unpack (computed as the remainder at
+  :meth:`TraceRecord.finish`, so the hops always sum to the wall).
 
-Destination spans travel back in the response meta (``"spans"``) and
-are merged client-side, so one offloaded call yields one structured
-timeline.  Completed traces land in a bounded in-memory sink (for tests
-and the ``trace`` control surface) and are optionally emitted as JSON
-log lines (``trace_log`` knob).
+Each hop may hold children, spans named ``<hop>.<part>`` whose
+``parent`` is the hop whose extent holds them; they never add to the
+wall (:meth:`TraceRecord.total_span_s` counts hops only):
+
+* ``queue.unpack`` — the request's ``unpack_message`` (direct path);
+* ``queue.admit`` — replay lookup and tenant admission (direct path);
+* ``execute.dispatch`` — the library call until it returns;
+* ``execute.device`` — ``jax.block_until_ready`` of its outputs;
+* ``respond.h2d`` — the ``jax.device_put`` of the arguments;
+* ``respond.d2h`` — ``np.asarray`` of the outputs;
+* ``respond.unpack`` — the client's unpack of the response.
+
+(On the coalesced path the request's unpack and admission precede its
+enqueue, so they stay in the remainder and carry no child.)
+
+A record also carries absolute ``time.time_ns()`` marks, one clock for
+both processes of a host and the clock the profiler's
+``profile_start_time`` is on: ``client.call`` / ``client.return`` (entry
+and exit of the session's ``call``), ``client.sent`` (request written;
+on the synchronous runtime, handed to the channel) / ``client.recv``
+(response frame in hand), and ``dest.in`` (entry to the destination's
+``handle``) / ``dest.out`` (response meta final, just before the pack).
+``compiles`` counts the backend compiles the destination saw during the
+call's dispatch, so a step that recompiled is named.
+
+Destination spans, children and marks travel back in the response meta
+(``"spans"``, ``"children"``, ``"marks"``) and are merged client-side,
+so one offloaded call yields one structured timeline.  Completed traces
+land in a bounded in-memory sink (for tests and the ``trace`` control
+surface) and are optionally emitted as JSON log lines (``trace_log``
+knob).  With tracing off no record exists, and every stamping site is a
+``trace is None`` test.
 
 :func:`emit` is also the structured replacement for the bare
 ``print()``\\ s in ``launch/serve.py``: one JSON object per line with a
@@ -59,8 +90,8 @@ class TraceRecord:
     the record).
     """
 
-    __slots__ = ("trace_id", "call_id", "fn", "spans", "wall_s",
-                 "created_s")
+    __slots__ = ("trace_id", "call_id", "fn", "spans", "marks", "compiles",
+                 "wall_s", "created_s")
 
     def __init__(self, trace_id: Optional[str] = None,
                  call_id: Optional[str] = None,
@@ -69,11 +100,21 @@ class TraceRecord:
         self.call_id = call_id
         self.fn = fn
         self.spans: list[dict] = []
+        self.marks: dict[str, int] = {}
+        self.compiles = 0
         self.wall_s: Optional[float] = None
         self.created_s = time.time()
 
-    def add(self, name: str, dur_s: float) -> None:
-        self.spans.append({"name": name, "dur_s": max(float(dur_s), 0.0)})
+    def add(self, name: str, dur_s: float,
+            parent: Optional[str] = None) -> None:
+        span = {"name": name, "dur_s": max(float(dur_s), 0.0)}
+        if parent is not None:
+            span["parent"] = parent
+        self.spans.append(span)
+
+    def mark(self, name: str, t_ns: Optional[int] = None) -> None:
+        """Stamp an absolute ``time.time_ns()`` mark (now, or ``t_ns``)."""
+        self.marks[name] = time.time_ns() if t_ns is None else int(t_ns)
 
     def merge(self, spans: Optional[dict]) -> None:
         """Fold destination-reported ``{name: seconds}`` spans in, in
@@ -87,16 +128,44 @@ class TraceRecord:
             if name not in SPAN_ORDER:
                 self.add(name, spans[name])
 
+    def merge_response(self, rmeta: dict) -> None:
+        """Fold a traced response's destination hops, their children
+        (``{"<hop>.<part>": seconds}``), marks and compile count in."""
+        self.merge(rmeta.get("spans"))
+        for name, dur in (rmeta.get("children") or {}).items():
+            self.add(name, dur, parent=name.split(".", 1)[0])
+        self.marks.update(rmeta.get("marks") or {})
+        self.compiles += int(rmeta.get("compiles", 0))
+
+    def retry(self) -> "TraceRecord":
+        """A fresh record for a retried attempt of the same call: the
+        failed attempt's spans are dropped, its ``client.call`` mark kept,
+        so the marks still bracket the whole call."""
+        fresh = TraceRecord(trace_id=self.trace_id, call_id=self.call_id,
+                            fn=self.fn)
+        if "client.call" in self.marks:
+            fresh.marks["client.call"] = self.marks["client.call"]
+        return fresh
+
     def total_span_s(self) -> float:
-        return sum(s["dur_s"] for s in self.spans)
+        """Summed hop spans (children excluded: they lie inside a hop)."""
+        return sum(s["dur_s"] for s in self.spans if "parent" not in s)
 
     def span_names(self) -> list[str]:
         return [s["name"] for s in self.spans]
 
+    def children(self, parent: str) -> dict[str, float]:
+        """``{name: seconds}`` of the children of one hop."""
+        out: dict[str, float] = {}
+        for s in self.spans:
+            if s.get("parent") == parent:
+                out[s["name"]] = out.get(s["name"], 0.0) + s["dur_s"]
+        return out
+
     def finish(self, wall_s: float) -> "TraceRecord":
         """Close the record against the observed end-to-end wall,
-        booking the unattributed remainder (response pack + wire flights
-        + unpack) as the ``respond`` span."""
+        booking the unattributed remainder (argument and output copies,
+        response pack, wire flights, unpack) as the ``respond`` span."""
         self.wall_s = float(wall_s)
         remainder = self.wall_s - self.total_span_s()
         self.add("respond", remainder)
@@ -105,7 +174,8 @@ class TraceRecord:
     def to_dict(self) -> dict:
         return {"trace_id": self.trace_id, "call_id": self.call_id,
                 "fn": self.fn, "wall_s": self.wall_s,
-                "spans": list(self.spans)}
+                "spans": list(self.spans), "marks": dict(self.marks),
+                "compiles": self.compiles}
 
 
 class TraceSink:
@@ -185,7 +255,10 @@ def merge_sharded(parent: Optional[TraceRecord],
         return parent
     slowest = max(done, key=lambda c: c.wall_s)
     for span in slowest.spans:
-        parent.add(span["name"], span["dur_s"])
+        parent.add(span["name"], span["dur_s"], span.get("parent"))
+    for name, t_ns in slowest.marks.items():
+        parent.marks.setdefault(name, t_ns)
+    parent.compiles += sum(c.compiles for c in done)
     return parent
 
 

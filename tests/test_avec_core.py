@@ -20,6 +20,7 @@ from repro.core.costmodel import (amortized_speedup, native_cycle_time,
                                   offload_cycle_time, speedup)
 from repro.core.library import make_model_library
 from repro.core.memory import release_buffer
+from repro.core.profiler import KEEP_CYCLES
 from repro.core.serialization import (DataTransfer, eq1_bytes, pack_message,
                                       tree_wire_bytes, unpack_message)
 from repro.core.transport import (Channel, LoopbackChannel, SimulatedChannel,
@@ -216,6 +217,21 @@ def test_profiler_accounting_sums():
     assert abs(b["communication_s"] - 0.1) < 1e-12
     assert abs(b["gpu_frac"] + b["communication_frac"] + b["other_frac"] - 1.0) < 1e-9
     assert p.fps() == pytest.approx(2 / 0.4)
+
+
+def test_profiler_memory_is_bounded():
+    n = KEEP_CYCLES + 6
+    p = AvecProfiler()
+    for i in range(n):
+        p.record_cycle(gpu_s=0.01, comm_s=0.02, bytes_sent=10,
+                       bytes_received=5, fn=f"f{i}")
+    # the totals cover every cycle; the ring keeps the most recent
+    assert [c.fn for c in p.cycles] == [f"f{i}" for i in range(6, n)]
+    b = p.breakdown()
+    assert b["cycles"] == n and b["bytes_sent"] == 10 * n
+    assert b["gpu_s"] == pytest.approx(0.01 * n)
+    assert p.per_cycle()["communication_s"] == pytest.approx(0.02)
+    assert p.fps() == pytest.approx(1 / 0.03)
 
 
 # ---------------------------------------------------------------------------

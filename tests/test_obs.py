@@ -17,6 +17,7 @@ from repro import avec
 from repro.core.executor import (DestinationExecutor, HostRuntime,
                                  PipelinedHostRuntime)
 from repro.core.interception import AvecSession
+from repro.core.serialization import unpack_message
 from repro.core.transport import TCPChannel, TCPServer
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -323,19 +324,160 @@ def test_trace_coalesce_span_on_batched_path():
         names = trace.span_names()
         assert "queue" in names and "coalesce" in names
         assert "execute" in names
+        # the batch's timed parts divide per call; the request's unpack and
+        # admission precede its enqueue, so queue carries no child here
+        assert set(trace.children("execute")) == {"execute.dispatch",
+                                                  "execute.device"}
+        assert set(trace.children("respond")) >= {"respond.h2d",
+                                                  "respond.d2h"}
+        assert trace.children("queue") == {}
     finally:
         ex.shutdown()
+
+
+CHILDREN = {"queue.unpack": "queue", "queue.admit": "queue",
+            "execute.dispatch": "execute", "execute.device": "execute",
+            "respond.h2d": "respond", "respond.d2h": "respond",
+            "respond.unpack": "respond"}
+MARKS = ("client.call", "client.sent", "dest.in", "dest.out", "client.recv",
+         "client.return")
+
+
+@pytest.mark.parametrize("rt_cls", [PipelinedHostRuntime, HostRuntime])
+def test_trace_children_and_marks_on_one_timeline(rt_cls):
+    obs_trace.get_sink().clear()
+    ex, server, rt, sess = _traced_session(rt_cls)
+    try:
+        x = np.ones((64, 64), np.float32)
+        sess.call("double", {"x": x})
+        sess.call("double", {"x": x})
+        tr = obs_trace.get_sink().last()
+        hops = {s["name"]: s["dur_s"] for s in tr.spans if "parent" not in s}
+        kids = [s for s in tr.spans if "parent" in s]
+        assert {s["name"]: s["parent"] for s in kids} == CHILDREN
+        m = tr.marks
+        # a client's send that returns after the destination took the frame
+        # (here: the destination's thread holding the GIL) overlaps the
+        # destination's work, and the remainder respond is short by as much
+        overlap = max(m["client.sent"] - m["dest.in"], 0) * 1e-9
+        for hop, slack in (("queue", 0.0), ("execute", 0.0),
+                           ("respond", overlap)):
+            assert sum(tr.children(hop).values()) <= \
+                hops[hop] + slack + 1e-9, hop
+        # execute's children are its two halves, read off the same clocks
+        assert sum(tr.children("execute").values()) == pytest.approx(
+            hops["execute"], rel=1e-6)
+        # children never add to the wall: the hops alone sum to it
+        assert tr.total_span_s() == pytest.approx(tr.wall_s, rel=1e-6)
+        assert sum(hops.values()) == pytest.approx(tr.wall_s, rel=1e-6)
+        assert set(m) == set(MARKS)
+        # the order causality fixes (the destination may read the frame
+        # before the client's write returns, so sent vs dest.in is free)
+        assert m["client.call"] <= m["client.sent"] <= m["client.recv"] \
+            <= m["client.return"]
+        assert m["client.call"] <= m["dest.in"] <= m["dest.out"] \
+            <= m["client.recv"]
+        assert tr.to_dict()["marks"] == m
+    finally:
+        rt.close()
+        server.stop()
+        ex.shutdown()
+
+
+def test_a_sharded_call_keeps_children_inside_their_hops():
+    parent = obs_trace.TraceRecord(fn="f")
+    shard = obs_trace.TraceRecord(fn="f[0:2]")
+    shard.add("execute", 0.004)
+    shard.add("execute.device", 0.003, parent="execute")
+    shard.mark("dest.in", 5)
+    shard.finish(0.010)
+    obs_trace.merge_sharded(parent, [shard])
+    parent.add("stitch", 0.001)
+    parent.finish(0.012)
+    assert parent.children("execute") == {"execute.device": 0.003}
+    assert parent.total_span_s() == pytest.approx(0.012)
+    assert parent.marks == {"dest.in": 5}
 
 
 def test_trace_disabled_is_zero_overhead_path(monkeypatch):
     monkeypatch.setenv("AVEC_TRACE_ENABLED", "0")
     assert obs_trace.start_trace(fn="x") is None
     assert obs_trace.finish_trace(None, 1.0) is None
+    import jax.profiler
+    annotations = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            annotations.append(name)
+            super().__init__(name, **kw)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    for rt_cls in (HostRuntime, PipelinedHostRuntime):
+        ex = DestinationExecutor({"tiny": _tiny_library()}, name="off-dest")
+        replies = []
+
+        def handle(raw, ex=ex):
+            resp = ex.handle(raw)
+            replies.append(unpack_message(bytes(resp))[0])
+            return resp
+        server = TCPServer(handle).start()
+        rt = rt_cls(TCPChannel.connect("127.0.0.1", server.port))
+        sess = AvecSession({"arch": "tiny"}, {"w": np.zeros(1, np.float32)},
+                           rt, "tiny")
+        try:
+            before = obs_trace.get_sink().completed
+            sess.call("double", {"x": np.ones(2, np.float32)})
+            assert obs_trace.get_sink().completed == before
+            run = [r for r in replies if "compute_s" in r]
+            assert len(run) == 1
+            for key in ("trace", "spans", "children", "marks", "compiles"):
+                assert key not in run[0], key
+            assert annotations == []
+        finally:
+            rt.close()
+            server.stop()
+            ex.shutdown()
+    # the same path traced does annotate: the stub above is live
+    monkeypatch.setenv("AVEC_TRACE_ENABLED", "1")
     ex, server, rt, sess = _traced_session(HostRuntime)
     try:
-        before = obs_trace.get_sink().completed
         sess.call("double", {"x": np.ones(2, np.float32)})
-        assert obs_trace.get_sink().completed == before
+        assert "avec.call:double" in annotations
+        assert "avec.execute.device" in annotations
+    finally:
+        rt.close()
+        server.stop()
+        ex.shutdown()
+
+
+def test_compile_counter_counts_a_fresh_jit_and_names_the_call():
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.executor import COMPILES
+    n0, s0 = COMPILES.count, COMPILES.seconds
+    jax.jit(lambda v: v * 3.0 + 1.0)(jnp.ones(7)).block_until_ready()
+    assert COMPILES.count >= n0 + 1 and COMPILES.seconds > s0
+
+    def fresh(params, state, args):
+        # a new jit on the first call only: that dispatch compiles
+        if "f" not in state:
+            state["f"] = jax.jit(lambda v: jnp.sin(v) * 5.0 - 2.0)
+        return {"y": state["f"](args["x"])}
+    ex = DestinationExecutor({"tiny": {"fresh": fresh}}, name="cc-dest")
+    server = TCPServer(ex.handle).start()
+    rt = HostRuntime(TCPChannel.connect("127.0.0.1", server.port))
+    sess = AvecSession({"arch": "tiny"}, {"w": np.zeros(1, np.float32)},
+                       rt, "tiny")
+    try:
+        obs_trace.get_sink().clear()
+        sess.call("fresh", {"x": np.ones(5, np.float32)})
+        assert obs_trace.get_sink().last().compiles >= 1
+        sess.call("fresh", {"x": np.ones(5, np.float32)})
+        assert obs_trace.get_sink().last().compiles == 0
+        vals = ex.metrics.sample_values()
+        assert vals["avec_backend_compiles_total"] == COMPILES.count
+        assert vals["avec_backend_compile_seconds_total"] == pytest.approx(
+            COMPILES.seconds)
     finally:
         rt.close()
         server.stop()
