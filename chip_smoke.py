@@ -17,8 +17,10 @@ Phases, in order; any failure raises and the script exits non-zero:
   granite   granite-3-2b at published widths (5.08 GB of bf16 weights
             shipped by ``put_model``): one 1x128 prefill, 16 greedy decode
             steps and one score, all finite and bit-identical to the same
-            library functions called directly on the resident params; the
-            chip's peak memory must show the weights are on it.
+            library functions called directly on the resident params; each
+            decode must donate the whole cache (updated in place, read from
+            ``avec_state_donated_bytes_total``); the chip's peak memory must
+            show the weights are on it.
 
 ``--four-chips`` runs only the multi-destination path: four destinations in
 this process, executor i on ``jax.devices()[i]``, each behind its own
@@ -189,13 +191,26 @@ def granite_phase(client, dest, host, seed: int, *, cfg=None,
     rng = np.random.default_rng(seed)
     tokens = rng.integers(0, cfg.vocab_size, (1, prompt)).astype(np.int32)
     targets = rng.integers(0, cfg.vocab_size, (1, prompt)).astype(np.int32)
+    ex = dest.executor
+
+    def donated() -> float:
+        return ex.metrics.sample_values()["avec_state_donated_bytes_total"]
+
     t2 = time.perf_counter()
     logits = [_host_np(sess.call("prefill", {"tokens": tokens}))["logits"]]
     fed = []
+    d0 = donated()
     for _ in range(steps):
         tok = np.argmax(logits[-1][:, -1], axis=-1).astype(np.int32)[:, None]
         fed.append(tok)
         logits.append(_host_np(sess.call("decode", {"tokens": tok}))["logits"])
+    d_bytes = donated() - d0
+    cache_bytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(
+        ex.cache.get(sess.fp)["state"]["cache"]))
+    log(f"[granite] decode donated {d_bytes:.0f} bytes of session state over "
+        f"{steps} calls; the cache is {cache_bytes} bytes")
+    check(d_bytes == steps * cache_bytes,
+          "decode did not update the session's cache in place")
     loss = _host_np(sess.call("score", {"tokens": tokens,
                                         "targets": targets}))["loss"]
     t_serve = time.perf_counter() - t2
@@ -203,7 +218,6 @@ def granite_phase(client, dest, host, seed: int, *, cfg=None,
         check(bool(np.all(np.isfinite(lg))), "non-finite logits")
     check(bool(np.isfinite(loss)), "non-finite score loss")
 
-    ex = dest.executor
     lib = ex.libraries["lm"]
     resident = ex.cache.get(sess.fp)["params"]
     state: dict = {}

@@ -237,6 +237,29 @@ class _CompileCounter:
 COMPILES = _CompileCounter()
 
 
+class _ByteCounter:
+    """A running total of bytes in this process, any thread adding."""
+
+    def __init__(self) -> None:
+        self._lock = _sanitize.make_lock("_ByteCounter._lock")
+        self._total = 0         # guarded-by: _lock
+
+    def add(self, nbytes: int) -> None:
+        with self._lock:
+            self._total += nbytes
+
+    @property
+    def total(self) -> int:
+        with self._lock:
+            return self._total
+
+
+#: Bytes of session state that library calls consumed by donation (the
+#: state's arrays the call deleted): a decode step that updates its cache in
+#: place adds the whole cache per call.
+STATE_DONATED = _ByteCounter()
+
+
 class _CallTrace:
     """The destination's half of one traced call: its span children, its
     ``dest.*`` marks and compile count (all returned in the response meta)
@@ -646,6 +669,11 @@ class DestinationExecutor:
             "DestinationExecutor._replay_lock")
         self._replay: dict[str, collections.OrderedDict] = {}  # guarded-by: _replay_lock
         self.replay_hits = 0                                   # guarded-by: _replay_lock
+        # one lock per session: a library call may donate the session's
+        # state buffers, so snapshot/restore wait for it (see _call)
+        self._sessions_lock = _sanitize.make_lock(
+            "DestinationExecutor._sessions_lock")
+        self._session_locks: dict[str, Any] = {}  # guarded-by: _sessions_lock
         self._coalescer = (_Coalescer(self._run_batch,
                                       self.coalesce_window_s,
                                       self.max_coalesce, tenant_weights)
@@ -664,6 +692,11 @@ class DestinationExecutor:
             "avec_backend_compile_seconds_total",
             "Seconds spent in backend compiles in the destination's process."
         ).bind(lambda: COMPILES.seconds)
+        self.metrics.counter(
+            "avec_state_donated_bytes_total",
+            "Bytes of session state handed to library calls as donated "
+            "buffers in the destination's process."
+        ).bind(lambda: float(STATE_DONATED.total))
 
     @property
     def coalesce_stats(self) -> dict:
@@ -1014,24 +1047,51 @@ class DestinationExecutor:
         self.cache.drop(meta["fp"])
         with self._replay_lock:
             self._replay.pop(meta["fp"], None)
+        with self._sessions_lock:
+            self._session_locks.pop(meta["fp"], None)
         return {"ok": True}, None, "raw"
 
     def _op_snapshot(self, meta, tree):
         entry = self.cache.get(meta["fp"])
-        state_np = jax.tree_util.tree_map(np.asarray, entry["state"])
+        with self._session_lock(meta["fp"]):
+            state_np = jax.tree_util.tree_map(np.asarray, entry["state"])
         return {"ok": True, "lib": entry["lib"]}, state_np, "raw"
 
     def _op_restore(self, meta, tree):
         entry = self.cache.get(meta["fp"])
-        entry["state"] = jax.device_put(tree, self.device)
+        state = jax.device_put(tree, self.device)
+        with self._session_lock(meta["fp"]):
+            entry["state"] = state
         return {"ok": True}, None, "raw"
+
+    def _session_lock(self, fp: str):
+        with self._sessions_lock:
+            lock = self._session_locks.get(fp)
+            if lock is None:
+                lock = self._session_locks[fp] = _sanitize.make_lock(
+                    "DestinationExecutor.session")
+            return lock
+
+    def _call(self, fp: str, entry: dict, fn: Callable, args) -> Any:
+        """``fn`` on the session, serialized with its snapshot and restore:
+        a call may donate the session's state buffers (deleting them), so
+        nothing else reads them until it has put the new state in place.
+        The state bytes it deleted count into ``STATE_DONATED``."""
+        with self._session_lock(fp):
+            held = [x for x in jax.tree_util.tree_leaves(entry["state"])
+                    if isinstance(x, jax.Array)]
+            out = fn(entry["params"], entry["state"], args)
+        donated = sum(x.nbytes for x in held if x.is_deleted())
+        if donated:
+            STATE_DONATED.add(donated)
+        return out
 
     def _run_one(self, meta, tree) -> tuple[dict, Any]:
         entry = self.cache.get(meta["fp"])
         fn = self.libraries[entry["lib"]][meta["fn"]]
         args = jax.device_put(tree, self.device)
         t0 = time.perf_counter()
-        out = fn(entry["params"], entry["state"], args)
+        out = self._call(meta["fp"], entry, fn, args)
         out = jax.block_until_ready(out)
         compute_s = time.perf_counter() - t0
         out_np = jax.tree_util.tree_map(np.asarray, out)
@@ -1053,7 +1113,7 @@ class DestinationExecutor:
         compiles0 = COMPILES.count
         t0 = time.perf_counter()
         with annotate("avec.execute.dispatch"):
-            out = fn(entry["params"], entry["state"], args)
+            out = self._call(meta["fp"], entry, fn, args)
         t_disp = time.perf_counter()
         with annotate("avec.execute.device"):
             out = jax.block_until_ready(out)

@@ -7,12 +7,24 @@ is what migration snapshots)."""
 from __future__ import annotations
 
 import functools
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.models import model as M
+
+
+def decode_jit(cfg):
+    """The decode step, jitted with its cache argument donated: the step
+    writes the new token into the cache's buffers in place
+    (``models.transformer.lm_decode_step``), and the call deletes the
+    arrays it was given."""
+    def _decode(params, cache, batch):
+        return M.decode_step(cfg, params, cache, batch)
+
+    return jax.jit(_decode, donate_argnums=1)
 
 
 def make_model_library(cfg, max_cache_len: int = 256) -> dict:
@@ -27,9 +39,7 @@ def make_model_library(cfg, max_cache_len: int = 256) -> dict:
         return M.prefill(cfg, params, batch, max_cache_len,
                          cache_dtype=jnp.float32)
 
-    @jax.jit
-    def _decode(params, cache, batch):
-        return M.decode_step(cfg, params, cache, batch)
+    _decode = decode_jit(cfg)
 
     def score(params, state, args):
         return {"loss": _loss(params, args)}
@@ -43,8 +53,14 @@ def make_model_library(cfg, max_cache_len: int = 256) -> dict:
     def decode(params, state, args):
         batch = dict(args)
         batch["pos"] = jnp.asarray(state["pos"], jnp.int32)
-        logits, cache = _decode(params, state["cache"], batch)
-        state["cache"] = cache
+        cache = state["cache"]
+        # the step deletes the cache it is given; where something besides
+        # this state holds it (a caller's copy of the state), the holder
+        # keeps it, and the step gets a copy.  Held here: the state,
+        # ``cache`` and getrefcount's argument.
+        if sys.getrefcount(cache) > 3:
+            cache = jax.tree_util.tree_map(jnp.copy, cache)
+        logits, state["cache"] = _decode(params, cache, batch)
         state["pos"] = int(state["pos"]) + 1
         return {"logits": logits}
 

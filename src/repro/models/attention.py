@@ -8,7 +8,8 @@ Two implementations, selected by ``cfg.attn_impl``:
   S/block_q (flash-style memory behaviour in pure jnp; the Pallas kernel in
   ``repro.kernels.flash_attention`` is the TPU-native version of this path).
 
-KV caches are plain pytrees: {"k": (B, S_max, K, hd), "v": (B, S_max, K, hd)}.
+KV caches are plain pytrees: {"k": (B, S_max, K, hd), "v": (B, S_max, K, hd)};
+decode reads and writes them stacked over layers, (L, B, S_max, K, hd).
 """
 from __future__ import annotations
 
@@ -168,11 +169,15 @@ def self_attention_prefill(cfg, p, x, positions, cache_len: int, *, rope: bool =
     return _out_proj(p, o), {"k": kc, "v": vc}
 
 
-def self_attention_decode(cfg, p, x, cache, pos, *, rope: bool = True):
-    """One-token decode.  x: (B,1,d); cache k/v: (B,S_max,K,hd); pos: () int32
-    shared write index, or (B,) per-row indices (continuous batching)."""
+def self_attention_decode(cfg, p, x, cache, layer, pos, *, rope: bool = True):
+    """One-token decode against a cache stacked over layers.  x: (B,1,d);
+    cache k/v: (L,B,S_max,K,hd); layer: this layer's index into them; pos:
+    () int32 shared write index, or (B,) per-row indices (continuous
+    batching).  Returns (out, the stacked k/v with this token written at
+    (layer, :, pos)): the write is one token, so a cache carried through
+    the layer scan and donated is updated in place, never copied whole."""
     B = x.shape[0]
-    S_max = cache["k"].shape[1]
+    S_max = cache["k"].shape[2]
     pos = jnp.asarray(pos, jnp.int32)
     per_row = pos.ndim == 1
     positions = pos[:, None] if per_row else jnp.full((B, 1), pos, jnp.int32)
@@ -180,16 +185,19 @@ def self_attention_decode(cfg, p, x, cache, pos, *, rope: bool = True):
     k_new, v_new = _project_kv(cfg, p, x, positions, rope)
     if per_row:
         rows = jnp.arange(B)
-        k = cache["k"].at[rows, pos].set(k_new[:, 0].astype(cache["k"].dtype))
-        v = cache["v"].at[rows, pos].set(v_new[:, 0].astype(cache["v"].dtype))
+        kc = cache["k"].at[layer, rows, pos].set(k_new[:, 0].astype(cache["k"].dtype))
+        vc = cache["v"].at[layer, rows, pos].set(v_new[:, 0].astype(cache["v"].dtype))
     else:
-        k = jax.lax.dynamic_update_slice(
-            cache["k"], k_new.astype(cache["k"].dtype), (0, pos, 0, 0))
-        v = jax.lax.dynamic_update_slice(
-            cache["v"], v_new.astype(cache["v"].dtype), (0, pos, 0, 0))
+        at = (layer, 0, pos, 0, 0)
+        kc = jax.lax.dynamic_update_slice(
+            cache["k"], k_new[None].astype(cache["k"].dtype), at)
+        vc = jax.lax.dynamic_update_slice(
+            cache["v"], v_new[None].astype(cache["v"].dtype), at)
+    k = jax.lax.dynamic_index_in_dim(kc, layer, 0, keepdims=False)
+    v = jax.lax.dynamic_index_in_dim(vc, layer, 0, keepdims=False)
     kv_pos = jnp.broadcast_to(jnp.arange(S_max, dtype=jnp.int32)[None], (B, S_max))
     o = _sdpa_naive(q, k, v, positions, kv_pos, causal=True, mixed=cfg.attn_mixed)
-    return _out_proj(p, o), {"k": k, "v": v}
+    return _out_proj(p, o), {"k": kc, "v": vc}
 
 
 # ---------------------------------------------------------------------------
@@ -207,13 +215,16 @@ def cross_attention(cfg, p, x, context):
     return _out_proj(p, o)
 
 
-def cross_attention_cached(cfg, p, x, cache):
-    """Decode-time cross-attention against precomputed context KV."""
+def cross_attention_cached(cfg, p, x, cache, layer):
+    """Decode-time cross-attention against precomputed context KV, stacked
+    over layers: cache cross_k/cross_v (L,B,Tc,K,hd); layer: this layer's
+    index into them."""
     B, S, _ = x.shape
     zeros_q = jnp.zeros((B, S), jnp.int32)
     q = _project_q(cfg, p, x, zeros_q, rope=False)
-    o = _sdpa_naive(q, cache["cross_k"], cache["cross_v"], None, None,
-                    causal=False, mixed=cfg.attn_mixed)
+    k, v = (jax.lax.dynamic_index_in_dim(cache[n], layer, 0, keepdims=False)
+            for n in ("cross_k", "cross_v"))
+    o = _sdpa_naive(q, k, v, None, None, causal=False, mixed=cfg.attn_mixed)
     return _out_proj(p, o)
 
 

@@ -11,6 +11,10 @@ Per-layer cache entries (decode):
   attn layer  -> {"k", "v"}
   mamba layer -> {"conv", "ssm"}
   cross layer -> additionally {"cross_k", "cross_v"}
+Prefill builds them per block and the scan stacks them.  Decode carries the
+stacked cache through the scan instead: each layer reads its slice at the
+block index and writes back only what it changes (one token of K/V; the
+whole SSM state), so a donated cache is updated in place.
 """
 from __future__ import annotations
 
@@ -106,8 +110,10 @@ def stacked_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16) -> dict:
 # ---------------------------------------------------------------------------
 
 def _apply_layer(cfg, j: int, p: dict, h, *, positions, mode: str,
-                 cache: dict | None, pos, context):
-    """One layer.  Returns (h, new_cache, aux_loss)."""
+                 cache: dict | None, pos, layer, context):
+    """One layer.  Returns (h, new_cache, aux_loss).  In decode, ``cache``
+    holds this layer's leaves stacked over blocks and ``layer`` is the
+    block index; the new cache is the stacked leaves, updated."""
     aux = jnp.zeros((), jnp.float32)
     new_cache: dict = {}
     kind = "attn" if "attn" in p else "mamba"
@@ -123,8 +129,8 @@ def _apply_layer(cfg, j: int, p: dict, h, *, positions, mode: str,
                 cfg, p["attn"], normed, positions, cache["k"].shape[1], rope=rope)
             new_cache.update(kv)
         else:  # decode
-            mix, kv = attn.self_attention_decode(cfg, p["attn"], normed, cache, pos,
-                                                 rope=rope)
+            mix, kv = attn.self_attention_decode(cfg, p["attn"], normed, cache,
+                                                 layer, pos, rope=rope)
             new_cache.update(kv)
     else:
         if mode == "train":
@@ -133,17 +139,17 @@ def _apply_layer(cfg, j: int, p: dict, h, *, positions, mode: str,
             mix, mc = mb.mamba_forward(cfg, p["mamba"], normed, return_cache=True)
             new_cache.update(mc)
         else:
-            mix, mc = mb.mamba_decode(cfg, p["mamba"], normed,
-                                      {"conv": cache["conv"], "ssm": cache["ssm"]})
-            new_cache.update(mc)
+            mix, mc = mb.mamba_decode(cfg, p["mamba"], normed, {
+                n: jax.lax.dynamic_index_in_dim(cache[n], layer, 0, keepdims=False)
+                for n in ("conv", "ssm")})
+            new_cache.update({n: jax.lax.dynamic_update_index_in_dim(
+                cache[n], mc[n].astype(cache[n].dtype), layer, 0) for n in mc})
 
     if cfg.parallel_block and "mlp" in p:
         # command-r style: shared-norm parallel attn + ffn residual
         y = apply_mlp(cfg, p["mlp"], normed)
         h = h + mix + y
         # cross/moe never combined with parallel_block in assigned archs
-        if cache is not None and kind == "attn" and mode == "decode":
-            pass
         return h, new_cache, aux
 
     h = h + mix
@@ -152,7 +158,7 @@ def _apply_layer(cfg, j: int, p: dict, h, *, positions, mode: str,
     if "cross" in p:
         cn = apply_norm(cfg, p["cross_norm"], h)
         if mode == "decode":
-            ca = attn.cross_attention_cached(cfg, p["cross"], cn, cache)
+            ca = attn.cross_attention_cached(cfg, p["cross"], cn, cache, layer)
             new_cache["cross_k"] = cache["cross_k"]
             new_cache["cross_v"] = cache["cross_v"]
         else:
@@ -176,14 +182,17 @@ def _apply_layer(cfg, j: int, p: dict, h, *, positions, mode: str,
 
 
 def apply_block(cfg, p: dict, h, *, positions, mode: str, cache: dict | None,
-                pos=None, context=None):
-    """Apply one block (list of layers).  Returns (h, new_cache, aux)."""
+                pos=None, layer=None, context=None):
+    """Apply one block (list of layers).  Returns (h, new_cache, aux).  In
+    decode ``cache`` is the whole stacked cache and ``layer`` the block's
+    index into it."""
     aux = jnp.zeros((), jnp.float32)
     new_layers = []
     for j, lp in enumerate(p["layers"]):
         lcache = cache["layers"][j] if cache is not None else None
         h, nc, a = _apply_layer(cfg, j, lp, h, positions=positions, mode=mode,
-                                cache=lcache, pos=pos, context=context)
+                                cache=lcache, pos=pos, layer=layer,
+                                context=context)
         new_layers.append(nc)
         aux = aux + a
     return h, {"layers": new_layers}, aux

@@ -125,21 +125,23 @@ def dec_step(cfg, params, cache, tokens, pos):
     positions = pos[:, None] if pos.ndim == 1 else jnp.full((B, 1), pos, jnp.int32)
     h = _embed_dec(cfg, params, tokens, positions)
 
-    def body(hh, xs):
-        p, c = xs
+    def body(carry, xs):
+        hh, cc = carry
+        i, p = xs
         n = apply_norm(cfg, p["mixer_norm"], hh)
-        mix, kv = attn.self_attention_decode(cfg, p["attn"], n, c, pos, rope=False)
+        mix, kv = attn.self_attention_decode(cfg, p["attn"], n, cc, i, pos,
+                                             rope=False)
         hh = hh + mix
         n = apply_norm(cfg, p["cross_norm"], hh)
-        hh = hh + attn.cross_attention_cached(cfg, p["cross"], n, c)
+        hh = hh + attn.cross_attention_cached(cfg, p["cross"], n, cc, i)
         n = apply_norm(cfg, p["ffn_norm"], hh)
         hh = hh + apply_mlp(cfg, p["mlp"], n)
-        nc = {**kv, "cross_k": c["cross_k"], "cross_v": c["cross_v"]}
-        return hh, nc
+        return (hh, {**cc, **kv}), None
 
-    h, new_cache = jax.lax.scan(body, h, (params["dec_blocks"], cache),
-                                unroll=True if cfg.unroll_blocks else 1)
-    return apply_norm(cfg, params["final_norm"], h), new_cache
+    (h, cache), _ = jax.lax.scan(
+        body, (h, cache), (jnp.arange(cfg.num_layers), params["dec_blocks"]),
+        unroll=True if cfg.unroll_blocks else 1)
+    return apply_norm(cfg, params["final_norm"], h), cache
 
 
 def encdec_init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):

@@ -69,23 +69,26 @@ def lm_prefill(cfg, params, tokens, cache_len: int, *, context=None,
 
 def lm_decode_step(cfg, params, cache, tokens, pos, *, context=None):
     """One-token decode.  tokens: (B,1); pos: () shared or (B,) per-row int32.
-    Returns (h, cache)."""
+    Returns (h, cache).  The stacked cache rides in the scan's carry and each
+    block writes only what it changes into it, so with the cache donated
+    the step updates it in place."""
     B = tokens.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
     positions = pos[:, None] if pos.ndim == 1 else jnp.full((B, 1), pos, jnp.int32)
     h = embed_tokens(cfg, params["embed"], tokens)
 
     def body(carry, xs):
-        hh = carry
-        bp, bc = xs
-        hh, nc, _ = apply_block(cfg, bp, hh, positions=positions, mode="decode",
-                                cache=bc, pos=pos, context=context)
-        return hh, nc
+        hh, cc = carry
+        i, bp = xs
+        hh, cc, _ = apply_block(cfg, bp, hh, positions=positions, mode="decode",
+                                cache=cc, pos=pos, layer=i, context=context)
+        return (hh, cc), None
 
-    h, new_cache = jax.lax.scan(body, h, (params["blocks"], cache),
-                                unroll=True if cfg.unroll_blocks else 1)
+    (h, cache), _ = jax.lax.scan(
+        body, (h, cache), (jnp.arange(num_blocks(cfg)), params["blocks"]),
+        unroll=True if cfg.unroll_blocks else 1)
     h = apply_norm(cfg, params["final_norm"], h)
-    return h, new_cache
+    return h, cache
 
 
 def lm_init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):

@@ -1,5 +1,5 @@
-"""Compile the main path's kernels and the OpenPose-lite forward for a
-described TPU v5e chip, at real widths, without a chip attached.
+"""Compile the main path's kernels, the served decode step and the
+OpenPose-lite forward for a described TPU v5e chip, at real widths, without a chip attached.
 
 What interpret mode cannot show — block shapes the Mosaic compiler refuses,
 fast-memory overuse, programs that do not fit — fails here.  Nothing runs,
@@ -10,13 +10,16 @@ one process may load the TPU compiler library, so only the worker that is
 given this file loads it."""
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.configs import get_arch
+from repro.configs import get_arch, with_overrides
+from repro.core.library import decode_jit
 from repro.kernels import ops
+from repro.models import model as M
 from repro.models.openpose import OpenPoseLite, op_forward, op_param_specs
 from repro.models.params import abstract_params
 
@@ -121,3 +124,31 @@ def test_openpose_forward_compiles_paper_geometry(one_chip):
     c = _compile(functools.partial(op_forward, net), params, frames)
     m = c.memory_analysis()
     assert m.output_size_in_bytes >= 46 * 82 * 57 * 4
+
+
+def test_decode_step_updates_the_granite_cache_in_place(one_chip):
+    """The served decode step at granite-3-2b's widths, on the 4096-slot
+    cache its prefill hands over: every cache byte aliased, and no layer's
+    K or V slice copied out of or back into the stacked cache."""
+    cfg = with_overrides(get_arch("granite-3-2b"), param_dtype="bfloat16",
+                         compute_dtype="bfloat16")
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = M.abstract_params(cfg)
+    _, cache = jax.eval_shape(
+        lambda p, t: M.prefill(cfg, p, {"tokens": t}, 4096,
+                               cache_dtype=jnp.float32),
+        params, jax.ShapeDtypeStruct((1, 8), jnp.int32))
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 1), jnp.int32),
+             "pos": jax.ShapeDtypeStruct((), jnp.int32)}
+    c = decode_jit(cfg).lower(on_chip(params), on_chip(cache),
+                              on_chip(batch)).compile()
+    nbytes = sum(a.size * a.dtype.itemsize
+                 for a in jax.tree_util.tree_leaves(cache))
+    assert c.memory_analysis().alias_size_in_bytes >= nbytes
+    assert not re.search(r"= \w+\[(1,)?1,4096,8,64\]\S* "
+                         r"(fusion|copy|dynamic-update-slice)\(", c.as_text())
