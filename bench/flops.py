@@ -1,4 +1,6 @@
 """Operations and bytes one served call needs, from the configuration alone.
+Each architecture counts them in ``bench/arch/<reference>.py``; this file
+holds what they share and the table of peaks.
 
 This counts the work the algorithm requires, never what an implementation
 happens to do, so a kernel that does less raises its share and no count can
@@ -7,8 +9,9 @@ push a share past 100 %:
 * causal attention counts only the keys at or before each query;
 * decode reads every weight once and the cached keys and values only up to
   the current position;
-* bytes are counted at the configuration's compute dtype (bfloat16 for both
-  configurations), whatever dtype the program keeps its cache in;
+* bytes are counted at the configuration's compute dtype (bfloat16 for
+  every configuration so far), whatever dtype the program keeps its cache
+  in;
 * the SSD state is read and written once per step, and its recurrence is
   counted as the linear-time scan (3 operations per state element to decay
   and update it, 2 to read it out);
@@ -33,67 +36,32 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def _itemsize(c: dict) -> int:
+def itemsize(c: dict) -> int:
     return {"bfloat16": 2, "float16": 2, "float32": 4}[c["torch_dtype"]]
 
 
-def _dense(c: dict, fn: str, n: int) -> tuple[float, float]:
-    d, L = c["hidden_size"], c["num_hidden_layers"]
-    H, K = c["num_attention_heads"], c["num_key_value_heads"]
-    hd, f, V = d // H, c["intermediate_size"], c["vocab_size"]
-    b = _itemsize(c)
-    per_layer = d * H * hd + 2 * d * K * hd + H * hd * d + 3 * d * f
-    weights = b * (L * per_layer + V * d) + 4 * (2 * L + 1) * d
-    kv_token = L * 2 * K * hd * b
-    head = 2 * d * V
-    if fn == "prefill":
-        attn = L * 4 * H * hd * n * (n + 1) / 2
-        flops = 2 * n * L * per_layer + attn + head
-        nbytes = weights + n * kv_token + 4 * n + 4 * V
-    elif fn == "decode":
-        attn = L * 4 * H * hd * (n + 1)
-        flops = 2 * L * per_layer + attn + head
-        nbytes = weights + (n + 1) * kv_token + 4 + 4 * V
-    else:
-        raise ValueError(fn)
-    return float(flops), float(nbytes)
-
-
-def _mamba2(c: dict, fn: str, n: int) -> tuple[float, float]:
-    d, L, V = c["d_model"], c["n_layer"], c["vocab_size"]
-    di = c["expand"] * d
-    H, P, N = di // c["headdim"], c["headdim"], c["d_state"]
-    gn, ck = c["ngroups"] * N, c["d_conv"]
-    conv_dim = di + 2 * gn
-    b = _itemsize(c)
-    per_layer = d * (2 * di + 2 * gn + H) + di * d
-    weights = (b * (L * (per_layer + ck * conv_dim + conv_dim) + V * d)
-               + 4 * (L * (3 * H + di + d) + d))
-    token = 2 * per_layer + 2 * ck * conv_dim + 5 * H * P * N
-    state = L * (H * P * N + (ck - 1) * conv_dim) * b
-    head = 2 * d * V
-    if fn == "prefill":
-        return (float(n * L * token + head),
-                float(weights + state + 4 * n + 4 * V))
-    if fn == "decode":
-        return float(L * token + head), float(weights + 2 * state + 4 + 4 * V)
-    raise ValueError(fn)
-
-
 def cost(c: dict, fn: str, n: int) -> tuple[float, float]:
-    """(operations, bytes) one call needs.  ``n``: the prompt length for
-    prefill; for decode the position of the token fed (it attends to n + 1
+    """(operations, bytes) one call needs, by the ``cost`` of
+    ``bench/arch/<reference>.py``.  ``n``: the prompt length for prefill;
+    for decode the position of the token fed (it attends to n + 1
     positions)."""
-    kind = c["reference"]
-    if kind == "dense_gqa":
-        return _dense(c, fn, n)
-    if kind == "mamba2":
-        return _mamba2(c, fn, n)
-    raise ValueError(f"no cost model for {kind!r}")
+    from bench import harness
+    return harness.arch_module(c["reference"]).cost(c, fn, n)
+
+
+def parts(c: dict, fn: str, n: int) -> dict:
+    """``{scope: (operations, bytes)}`` that each named program scope of
+    one call needs, by the ``parts`` of ``bench/arch/<reference>.py``."""
+    from bench import harness
+    return harness.arch_module(c["reference"]).parts(c, fn, n)
+
+
+def least_s(ops: float, nbytes: float, peak: dict) -> float:
+    """Roofline time: the larger of operations over peak rate and bytes
+    over peak bandwidth."""
+    return max(ops / peak["bf16_flops_per_s"], nbytes / peak["hbm_bytes_per_s"])
 
 
 def min_time_s(c: dict, fn: str, n: int, peak: dict) -> float:
-    """Roofline time of one call: the larger of operations over peak rate
-    and bytes over peak bandwidth."""
-    ops, nbytes = cost(c, fn, n)
-    return max(ops / peak["bf16_flops_per_s"], nbytes / peak["hbm_bytes_per_s"])
+    """Roofline time of one call."""
+    return least_s(*cost(c, fn, n), peak)

@@ -10,9 +10,12 @@ per-layer metric is a file of its own, found by the name
   bench/traffic/<traffic>.json    parameters for ``bench/traffic.py``
   bench/metrics/<metric>.py       ``read(ctx) -> float | None``
   bench/reference/<kind>.py       plain float32 reference of an architecture
+  bench/arch/<kind>.py            the program's side of an architecture: its
+                                  ``ModelConfig`` and the cost of its calls
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -84,37 +87,20 @@ def reference_module(kind: str):
                        f"bench_reference_{kind}")
 
 
+@functools.cache
+def arch_module(kind: str):
+    """``bench/arch/<kind>.py``: the program's ``ModelConfig`` for a
+    configuration of that kind (``program_config``) and the operations and
+    bytes its calls need (``cost``, ``parts``; see ``bench/flops.py``)."""
+    sys.path.insert(0, os.path.join(ROOT, "src"))
+    return load_module(os.path.join(BENCH, "arch", f"{kind}.py"),
+                       f"bench_arch_{kind}")
+
+
 def program_config(c: dict):
     """The program's ``ModelConfig`` for configuration file ``c``: the
     registered architecture with the file's sizes laid over it."""
-    sys.path.insert(0, os.path.join(ROOT, "src"))
-    from repro.configs import get_arch, with_overrides
-    from repro.configs.base import SSMConfig
-    base = get_arch(c["arch"])
-    dtype = c["torch_dtype"]
-    if c["reference"] == "dense_gqa":
-        kw = dict(d_model=c["hidden_size"], num_layers=c["num_hidden_layers"],
-                  num_heads=c["num_attention_heads"],
-                  num_kv_heads=c["num_key_value_heads"],
-                  head_dim=c["hidden_size"] // c["num_attention_heads"],
-                  d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-                  rope_theta=c["rope_theta"],
-                  tie_embeddings=c["tie_word_embeddings"])
-    elif c["reference"] == "mamba2":
-        heads = c["expand"] * c["d_model"] // c["headdim"]
-        kw = dict(d_model=c["d_model"], num_layers=c["n_layer"],
-                  num_heads=heads, num_kv_heads=heads,
-                  head_dim=c["d_model"] // heads,
-                  vocab_size=c["vocab_size"],
-                  tie_embeddings=c["tie_embeddings"],
-                  ssm=SSMConfig(d_state=c["d_state"], expand=c["expand"],
-                                head_dim=c["headdim"],
-                                conv_kernel=c["d_conv"],
-                                chunk=c["chunk_size"],
-                                n_groups=c["ngroups"]))
-    else:
-        raise ValueError(f"no program mapping for reference {c['reference']!r}")
-    return with_overrides(base, param_dtype=dtype, compute_dtype=dtype, **kw)
+    return arch_module(c["reference"]).program_config(c)
 
 
 def send(stream, obj: dict) -> None:

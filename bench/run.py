@@ -347,7 +347,10 @@ def measure(args, traffic, server, client):
     if end.get("trace"):
         tr = end["trace"]
         log(f"trace: busy {tr['busy_s']:.6f}s of {window_s:.6f}s; module "
-            f"seconds {tr['module_s']}, executions {tr['module_count']}")
+            f"seconds {tr['module_s']}, executions {tr['module_count']}; "
+            f"scope seconds {tr['scope_s']}")
+    log(f"counters in the window: "
+        f"{ {k: v for k, v in end['counters'].items() if v} }")
     return {"records": records, "t0": t0, "seconds": seconds, "end": end,
             "window_s": window_s}
 
@@ -398,7 +401,8 @@ def result_line(args, bench, cell, c, server, client, m, ref) -> dict:
         dev["busy_s"] = tr["busy_s"]
         dev["window_s"] = m["window_s"]
         ctx = {"c": c, "calls": call_spans(client), "trace": tr,
-               "window_s": m["window_s"], "peak": flops.peaks(dev["kind"])}
+               "counters": m["end"]["counters"], "window_s": m["window_s"],
+               "peak": flops.peaks(dev["kind"])}
         for spec in harness.per_layer_for(bench, cell["name"]):
             v = harness.metric_reader(spec["name"]).read(ctx)
             if v is not None:

@@ -9,8 +9,10 @@ session will present, so ``ensure_model`` hits and no weights cross the
 wire.  It then answers JSON-line commands from the run on stdin:
 
   window_start   start the profiler (traced runs); mark the compile count
+                 and the destination's counters
   window_end     stop the profiler and reduce its trace; report compiles in
-                 the window and the chip's peak memory
+                 the window, the counters' growth in it and the chip's peak
+                 memory
   reference      stop serving, free the program's state, regenerate the
                  weights from the seed and run the plain reference (and,
                  when asked, the int8 control) over the sampled requests
@@ -133,6 +135,7 @@ class Server:
         self.trace_dir = None
         self.t_trace0 = 0.0
         self.mark = 0
+        self.counters0: dict = {}
 
     @property
     def fp(self) -> str:
@@ -152,9 +155,18 @@ class Server:
         log(f"installed {nbytes} bytes of weights for seed {seed} in "
             f"{time.monotonic() - t0:.3f}s")
 
+    def counters(self) -> dict:
+        """The destination's counters, as its ``/metrics`` exposes them:
+        every ``*_total`` view, keyed by name (and labels, where it has
+        any)."""
+        return {k: v for k, v in
+                self.dest.executor.metrics.sample_values().items()
+                if k.partition("{")[0].endswith("_total")}
+
     # -- commands -------------------------------------------------------
     def window_start(self, msg: dict) -> dict:
         self.mark = self.watch.compiles
+        self.counters0 = self.counters()
         if msg.get("trace"):
             self.trace_dir = os.path.join(harness.OUT, f"trace-{os.getpid()}")
             shutil.rmtree(self.trace_dir, ignore_errors=True)
@@ -169,6 +181,8 @@ class Server:
 
     def window_end(self, msg: dict) -> dict:
         out = {"compiles": self.watch.compiles - self.mark,
+               "counters": {k: v - self.counters0.get(k, 0.0)
+                            for k, v in self.counters().items()},
                "peak_bytes": peak_bytes(self.dev)}
         if self.trace_dir is not None:
             out["window_s"] = time.monotonic() - self.t_trace0

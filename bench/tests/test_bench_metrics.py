@@ -62,6 +62,24 @@ def test_roofline_is_least_time_over_the_program_s_device_time():
         100 * need / 0.12)
 
 
+def test_sdpa_roofline_is_the_scope_s_least_time_over_its_device_time():
+    tr = {"busy_s": 1.0, "module_s": {"decode": 0.025},
+          "scope_s": {"decode": {"avec.sdpa": 0.002, "": 0.023}}}
+    need = sum(flops.least_s(*flops.parts(GRANITE, "decode", n)["avec.sdpa"],
+                             PEAK) for n in (512, 513, 514))
+    # bound by the K/V bytes: 513..515 positions of 81,920 bytes
+    assert need == pytest.approx(81920 * (513 + 514 + 515) / 819e9)
+    assert read("sdpa_roofline.decode", _ctx(CALLS, tr)) == pytest.approx(
+        100 * need / 0.002)
+    # a program without the scope, and a kind that counts none
+    tr["scope_s"]["decode"] = {"": 0.025}
+    assert read("sdpa_roofline.decode", _ctx(CALLS, tr)) is None
+    ctx = _ctx(CALLS, {"busy_s": 1.0, "module_s": {"decode": 0.025},
+                       "scope_s": {"decode": {"avec.sdpa": 0.002}}})
+    ctx["c"] = harness.load_config(harness.config_path("mamba2-130m"))
+    assert read("sdpa_roofline.decode", ctx) is None
+
+
 def test_idle_share():
     assert read("device_idle_pct", _ctx(CALLS, {"busy_s": 2.5,
                                                 "module_s": {}},
@@ -71,7 +89,7 @@ def test_idle_share():
 @pytest.mark.parametrize("name", ["wire_ms.prefill", "wire_ms.decode",
                                   "mfu_pct.prefill", "mfu_pct.decode",
                                   "prefill_roofline", "decode_roofline",
-                                  "device_idle_pct"])
+                                  "device_idle_pct", "sdpa_roofline.decode"])
 def test_a_reader_with_nothing_to_read_returns_nothing(name):
     # no calls of the kind, and a trace in which the program never ran: the
     # metric is left out of the line, never reported as 0

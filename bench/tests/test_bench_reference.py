@@ -5,12 +5,10 @@ import copy
 import os
 
 import numpy as np
-import pytest
 
 from bench import harness, weights
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
-CONFIGS = ["tiny-dense", "tiny-mamba2"]
 
 
 def _config(name, dtype=None):
@@ -46,9 +44,8 @@ def _inputs(c, seed, P=37, n_out=9):
     return params, prompt, n_out
 
 
-@pytest.mark.parametrize("name", CONFIGS)
-def test_reference_matches_the_served_program(name):
-    c = _config(name, "float32")           # float32 weights and compute
+def test_reference_matches_the_served_program(tiny_config):
+    c = _config(tiny_config, "float32")    # float32 weights and compute
     params, prompt, n_out = _inputs(c, 2 ** 31 + 11)
     logits, served = _serve(c, params, prompt, n_out)
     ref = harness.reference_module(c["reference"]).Reference(c)
@@ -59,9 +56,8 @@ def test_reference_matches_the_served_program(name):
     np.testing.assert_allclose(logits, want, rtol=0, atol=2e-4 * scale)
 
 
-@pytest.mark.parametrize("name", CONFIGS)
-def test_reference_is_causal_and_ignores_padding(name):
-    c = _config(name)
+def test_reference_is_causal_and_ignores_padding(tiny_config):
+    c = _config(tiny_config)
     params, prompt, _ = _inputs(c, 5)
     ref = harness.reference_module(c["reference"]).Reference(c)
     rows = np.arange(len(prompt))
@@ -91,12 +87,11 @@ def _numbers(c, seed):
             "ctrl_gap": float((best - want[r, pick]).max())}
 
 
-@pytest.mark.parametrize("name", CONFIGS)
-def test_int8_control_fails_where_bf16_serving_passes(name):
+def test_int8_control_fails_where_bf16_serving_passes(tiny_config):
     """The comparison at the configuration's own precision (bfloat16) and
     its control one step below (int8 weights and activations).  The tiny
     files' limits lie between the two readings."""
-    c = _config(name)
+    c = _config(tiny_config)
     lim = c["limits"]
     for seed in (1, 2, 3):
         n = _numbers(c, seed)

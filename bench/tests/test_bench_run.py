@@ -121,9 +121,8 @@ def test_no_tpu_no_result():
     assert '"metrics"' not in p.stdout
 
 
-@pytest.mark.parametrize("config", ["tiny-dense", "tiny-mamba2"])
-def test_sound_run_is_correct(config):
-    out = _run(config, "tiny-chat", 2 ** 31 + 99)
+def test_sound_run_is_correct(tiny_config):
+    out = _run(tiny_config, "tiny-chat", 2 ** 31 + 99)
     assert out["correct"], out["checks"]
     assert out["failed"] == 0 and out["attempted"] > 0
     assert set(out["metrics"]) >= {"setup_s", "tokens_per_s", "ttft_p50_ms",
@@ -132,28 +131,71 @@ def test_sound_run_is_correct(config):
     assert out["device"]["platform"] == "cpu"
 
 
-@pytest.mark.parametrize("config", ["tiny-dense", "tiny-mamba2"])
 @pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_broken_timed_path_is_not_correct(fault, config):
+def test_broken_timed_path_is_not_correct(fault, tiny_config):
     code = FAULTY_SERVER.format(root=harness.ROOT,
                                 src=os.path.join(harness.ROOT, "src"),
                                 server=SERVER, body=FAULTS[fault])
-    out = _run(config, "tiny-chat", 2 ** 31 + 7,
+    out = _run(tiny_config, "tiny-chat", 2 ** 31 + 7,
                server_cmd=[sys.executable, "-c", code])
     assert out["attempted"] > 0 and out["failed"] == 0
     assert not out["correct"], out["checks"]
 
 
-@pytest.mark.parametrize("config", ["tiny-dense", "tiny-mamba2"])
-def test_int8_control_served_is_not_correct(config):
+def test_int8_control_served_is_not_correct(tiny_config):
     """The control judged by the code that judges the program: its logits
     cross the wire as received logits and fail the comparison."""
     code = CONTROL_SERVER.format(root=harness.ROOT,
                                  src=os.path.join(harness.ROOT, "src"),
                                  server=SERVER)
-    out = _run(config, "tiny-chat", 2 ** 31 + 5,
+    out = _run(tiny_config, "tiny-chat", 2 ** 31 + 5,
                server_cmd=[sys.executable, "-c", code])
     assert out["attempted"] > 0 and out["failed"] == 0
     assert not out["correct"], out["checks"]
     ck = out["checks"]["logit_err"]
     assert ck["value"] > ck["limit"], out["checks"]
+
+
+def test_the_window_reports_the_destination_s_counters():
+    """Each tiny-dense decode call in the window donates its whole cache,
+    and the window's counters grow by that."""
+    from bench import run
+    args = _args(2 ** 31 + 3)
+    _, _, c, _, traffic, server, client = run.setup(
+        args, allow_cpu=True, spec=_spec("tiny-dense", "tiny-chat"))
+    try:
+        end = run.measure(args, traffic, server, client)["end"]
+    finally:
+        client.close()
+        server.close()
+    decodes = sum(1 for _, fn, _ in client.calls if fn == "decode")
+    # K and V of every layer and slot, bfloat16
+    hd = c["hidden_size"] // c["num_attention_heads"]
+    cache = (2 * c["num_hidden_layers"] * c["serving"]["max_cache_len"]
+             * c["num_key_value_heads"] * hd * 2)
+    assert decodes > 0
+    assert end["counters"]["avec_state_donated_bytes_total"] == \
+        decodes * cache
+    assert end["counters"]["avec_backend_compiles_total"] == 0
+
+
+def test_traced_readers_get_the_window_s_counters(monkeypatch):
+    from bench import run
+    seen = []
+    monkeypatch.setattr(harness, "metric_reader", lambda name: types.
+                        SimpleNamespace(read=lambda ctx: seen.append(ctx)))
+    bench, cell, c_path, _ = run.load_cell("granite-chat")
+    trace = {"busy_s": 1.0, "module_s": {}, "module_count": {},
+             "scope_s": {}, "device_ops": [], "idle_gaps": []}
+    m = {"records": [], "t0": 0.0, "seconds": 10.0, "window_s": 10.0,
+         "sample_requests": 0, "sample_tokens": 0,
+         "end": {"peak_bytes": 1, "compiles": 0, "trace": trace,
+                 "counters": {"avec_state_donated_bytes_total": 3.0}}}
+    server = types.SimpleNamespace(ready={"device": {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1}})
+    run.result_line(types.SimpleNamespace(trace=1), bench, cell,
+                    harness.load_config(c_path), server,
+                    types.SimpleNamespace(calls=[]), m, None)
+    assert len(seen) == len(harness.per_layer_for(bench, "granite-chat"))
+    assert all(ctx["counters"] == {"avec_state_donated_bytes_total": 3.0}
+               for ctx in seen)

@@ -1,5 +1,6 @@
 """The reduction from a profiler trace to device numbers: the arithmetic on
 planes built by hand, and the reading of a trace recorded on the CPU."""
+import struct
 from types import SimpleNamespace as NS
 
 import jax
@@ -72,6 +73,114 @@ def test_nested_operations_count_once():
     assert trc.short_name("jit__decode(7290837971745813548)") == "jit__decode"
 
 
+# framework names (the HLO op_name a TPU trace keeps in each operation's
+# event metadata), by (program id, operation event name)
+SCOPED_MODULES = [("jit__decode(7)", 0, 100), ("jit__decode(7)", 200, 100),
+                  ("jit__prefill(8)", 400, 50)]
+SCOPED_OPS = [("%while.1", 0, 90), ("%fusion.2", 10, 30),
+              ("%fusion.3", 50, 20), ("%copy.4", 92, 8),
+              ("%while.1", 200, 90), ("%fusion.2", 210, 30),
+              ("%fusion.3", 250, 20), ("%copy.4", 292, 8),
+              ("%fusion.2", 400, 40)]
+NAMES = {
+    ("7", "%while.1"): "jit(_decode)/while",
+    ("7", "%fusion.2"): "jit(_decode)/while/body/avec.attn/avec.sdpa/"
+                        "bskgh,btkh->bkgst/dot_general:",
+    ("7", "%fusion.3"): "jit(_decode)/while/body/avec.sdpa/reduce_sum:",
+    # the same operation name in another program: not the decode's
+    ("8", "%fusion.2"): "jit(_prefill)/avec.other/add:",
+}
+
+
+def test_scope_time_by_the_innermost_avec_segment():
+    out = trc.scope_ns(SCOPED_OPS, SCOPED_MODULES, NAMES)
+    # the loop's own time (90 less its body's 50) counts once, under its
+    # own name; the copy has no framework name: both unscoped
+    assert dict(out["decode"]) == {"avec.sdpa": 2 * (30 + 20),
+                                   "": 2 * (40 + 8)}
+    assert dict(out["prefill"]) == {"avec.other": 40}
+    assert trc.scope("jit(f)/avec.a/x/avec.b/dot_general:") == "avec.b"
+    assert trc.scope("jit(f)/while/body/dot_general:") == ""
+    assert trc.program_id("jit__decode(7290837971745813548)") == \
+        "7290837971745813548"
+
+
+def test_scope_time_sums_to_the_module_s_own_operation_time():
+    out = trc.scope_ns(SCOPED_OPS, SCOPED_MODULES, NAMES)
+    own = trc.self_ns(SCOPED_OPS)
+    for fn in ("decode", "prefill"):
+        want = sum(own[i] for i in range(len(SCOPED_OPS))
+                   if (i < 8) == (fn == "decode"))
+        assert sum(out[fn].values()) == pytest.approx(want)
+    # without framework names every operation is unscoped
+    assert dict(trc.scope_ns(SCOPED_OPS, SCOPED_MODULES, {})["decode"]) == {
+        "": sum(own[:8])}
+
+
+def test_scope_time_is_averaged_over_chips():
+    other = _device(1, [("%fusion.2", 0, 60)], [("jit__decode(9)", 0, 60)])
+    names = {f"{trc.DEVICE_PREFIX}0": NAMES,
+             f"{trc.DEVICE_PREFIX}1": {("9", "%fusion.2"): "a/avec.sdpa/b:"}}
+    out = trc.reduce_planes([_device(0, SCOPED_OPS, SCOPED_MODULES), other],
+                            [HOST], names)
+    assert out["scope_s"]["decode"] == {
+        "avec.sdpa": pytest.approx((100 + 60) / 2 * 1e-9),
+        "": pytest.approx(96 / 2 * 1e-9)}
+    assert out["scope_s"]["prefill"] == {"avec.other": pytest.approx(20e-9)}
+    # no names given: all of a function's own time is unscoped
+    out = trc.reduce_planes([_device(0, SCOPED_OPS, SCOPED_MODULES)], [HOST])
+    assert out["scope_s"]["decode"] == {"": pytest.approx(196e-9)}
+
+
+def _pb(fields) -> bytes:
+    """A protobuf message from (field number, value) pairs: an int is a
+    varint, bytes or str a length-delimited field, a float a double."""
+    def varint(v):
+        out = b""
+        while True:
+            out += bytes([v & 0x7F | (0x80 if v > 0x7F else 0)])
+            v >>= 7
+            if not v:
+                return out
+    out = b""
+    for num, v in fields:
+        if isinstance(v, float):
+            out += varint(num << 3 | 1) + struct.pack("<d", v)
+        elif isinstance(v, int):
+            out += varint(num << 3) + varint(v)
+        else:
+            v = v.encode() if isinstance(v, str) else v
+            out += varint(num << 3 | 2) + varint(len(v)) + v
+    return out
+
+
+def test_framework_names_from_a_serialized_xspace():
+    stat_md = [(5, _pb([(1, k), (2, _pb([(1, k), (2, name)]))]))
+               for k, name in ((1, "tf_op"), (2, "program_id"),
+                               (3, "flops"), (4, "hlo_category"))]
+
+    def event_md(k, name, *stats):
+        return (4, _pb([(1, k), (2, _pb([(1, k), (2, name),
+                                         *((5, _pb(st)) for st in stats)]))]))
+
+    dev = _pb([(1, 3), (2, "/device:TPU:0"), (3, _pb([(2, "XLA Ops")])),
+               *stat_md,
+               event_md(1, "%fusion.2 = bf16[8] fusion(...)",
+                        [(1, 1), (5, "jit(_decode)/avec.sdpa/dot_general:")],
+                        [(1, 2), (3, 2 ** 63 + 5)], [(1, 3), (2, 1.5)]),
+               # a name given by reference to a stat's metadata
+               event_md(2, "%copy.4", [(1, 2), (3, 7)], [(1, 1), (7, 4)]),
+               # no framework name: left out
+               event_md(3, "%while.1", [(1, 2), (3, 7)])])
+    host = _pb([(1, 4), (2, "/host:CPU"), *stat_md,
+                event_md(1, "%x", [(1, 1), (5, "host op")])])
+    raw = _pb([(1, host), (1, dev), (2, "an error")])
+    assert trc.framework_names(raw, {"/device:TPU:0"}) == {"/device:TPU:0": {
+        (str(2 ** 63 + 5), "%fusion.2 = bf16[8] fusion(...)"):
+            "jit(_decode)/avec.sdpa/dot_general:",
+        ("7", "%copy.4"): "hlo_category"}}
+
+
 def test_no_device_plane_is_an_error():
     with pytest.raises(ValueError, match="no device plane"):
         trc.reduce_planes([], [HOST])
@@ -96,6 +205,10 @@ def test_a_trace_recorded_on_the_cpu(tmp_path):
     host = [e.name for line in planes[trc.HOST_PLANE].lines
             for e in line.events]
     assert any("_prefill" in name for name in host)
+    # the CPU's operations carry no framework name in their metadata
+    with open(trc.find_xplane(str(tmp_path)), "rb") as f:
+        assert trc.framework_names(f.read(), set(planes)) == {
+            name: {} for name in planes}
     # the CPU has no device plane: the reduction refuses the trace rather
     # than report a device number from it
     with pytest.raises(ValueError, match="no device plane"):
