@@ -15,9 +15,10 @@ from repro.configs import moonshot_v1_16b_a3b # noqa: F401
 from repro.configs import arctic_480b         # noqa: F401
 from repro.configs import llama_3_2_vision_90b  # noqa: F401
 from repro.configs import jamba_1_5_large_398b  # noqa: F401
+from repro.configs import granite_4_0_h_small   # noqa: F401
 
 ARCH_IDS = [
     "granite-3-2b", "deepseek-7b", "minicpm-2b", "command-r-plus-104b",
     "whisper-medium", "mamba2-130m", "moonshot-v1-16b-a3b", "arctic-480b",
-    "llama-3.2-vision-90b", "jamba-1.5-large-398b",
+    "llama-3.2-vision-90b", "jamba-1.5-large-398b", "granite-4.0-h-small",
 ]

@@ -30,6 +30,14 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     every: int = 1                 # MoE layer stride (jamba: every 2nd layer)
+    # expert parallelism: this chip holds experts [first_held, first_held +
+    # held) of the router's num_experts (held 0: all of them)
+    held: int = 0
+    first_held: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -64,7 +72,14 @@ class ModelConfig:
     use_bias: bool = False
     parallel_block: bool = False   # command-r style parallel attn+ffn residual
     rope_theta: float = 10000.0
+    rope: bool = True              # False: no position embedding (NoPE)
     tie_embeddings: bool = False
+    # Granite scalars; the defaults are the plain block
+    norm_eps: float = 1e-6
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attn_scale: float = 0.0        # 0 -> head_dim ** -0.5
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     # hybrid (jamba): attention at layer i % attn_every == attn_offset
@@ -159,13 +174,13 @@ class ModelConfig:
                 total += in_proj + conv + out + nh  # +A_log/D per head
             if self.layer_has_moe(i):
                 m = self.moe
-                ff = m.num_experts * 3 * d * m.d_ff
+                ff = m.n_held * 3 * d * m.d_ff
                 router = d * m.num_experts
                 total += ff + router
                 if m.dense_residual:
                     total += 3 * d * self.d_ff
                 if active_only:
-                    total -= (m.num_experts - m.top_k) * 3 * d * m.d_ff
+                    total -= max(m.n_held - m.top_k, 0) * 3 * d * m.d_ff
             else:
                 n_mats = 3 if self.act == "swiglu" else 2
                 total += n_mats * d * self.d_ff

@@ -99,6 +99,7 @@ Runtime stats (``PipelinedHostRuntime.stats()``) — exported to
 from __future__ import annotations
 
 import collections
+import functools
 import itertools
 import math
 import random
@@ -252,6 +253,11 @@ class _ByteCounter:
     def total(self) -> int:
         with self._lock:
             return self._total
+
+
+def _lib_samples(lib_name: str, samples: Callable) -> list:
+    """A library's own view samples, labelled with the library's name."""
+    return [({**labels, "lib": lib_name}, value) for labels, value in samples()]
 
 
 #: Bytes of session state that library calls consumed by donation (the
@@ -697,6 +703,11 @@ class DestinationExecutor:
             "Bytes of session state handed to library calls as donated "
             "buffers in the destination's process."
         ).bind(lambda: float(STATE_DONATED.total))
+        # views a library keeps of its own (core.library.Library.counters)
+        for lib_name, lib in libraries.items():
+            for view, (doc, samples) in getattr(lib, "counters", {}).items():
+                self.metrics.counter(view, doc).bind_samples(
+                    functools.partial(_lib_samples, lib_name, samples))
 
     @property
     def coalesce_stats(self) -> dict:

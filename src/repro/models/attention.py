@@ -80,8 +80,10 @@ def _out_proj(p, o):
 # Core scaled-dot-product attention (GQA), mask by positions
 # ---------------------------------------------------------------------------
 
-def _sdpa_naive(q, k, v, q_pos, kv_pos, causal: bool, mixed: bool = False):
+def _sdpa_naive(q, k, v, q_pos, kv_pos, causal: bool, mixed: bool = False,
+                scale: float = 0.0):
     """q: (B,S,H,hd); k/v: (B,T,K,hd); q_pos: (B,S) | None; kv_pos: (B,T) | None.
+    Scores are scaled by ``scale`` (0: hd ** -0.5).
 
     ``mixed=False`` (paper-faithful baseline): upcast operands to fp32 before
     the score/value matmuls — simple but doubles the bytes moved for bf16
@@ -92,7 +94,7 @@ def _sdpa_naive(q, k, v, q_pos, kv_pos, causal: bool, mixed: bool = False):
     B, S, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]
     G = H // K
-    scale = hd ** -0.5
+    scale = scale or hd ** -0.5
     if mixed:
         qr = q.reshape(B, S, K, G, hd)
         scores = jnp.einsum("bskgh,btkh->bkgst", qr, k,
@@ -114,11 +116,12 @@ def _sdpa_naive(q, k, v, q_pos, kv_pos, causal: bool, mixed: bool = False):
 
 
 def _sdpa_blocked(q, k, v, q_pos, kv_pos, causal: bool, block_q: int,
-                  unroll: bool = False, mixed: bool = False):
+                  unroll: bool = False, mixed: bool = False,
+                  scale: float = 0.0):
     """lax.scan over query blocks: peak score memory B*K*G*block_q*T."""
     B, S, H, hd = q.shape
     if S <= block_q:
-        return _sdpa_naive(q, k, v, q_pos, kv_pos, causal, mixed)
+        return _sdpa_naive(q, k, v, q_pos, kv_pos, causal, mixed, scale)
     pad = (-S) % block_q
     if pad:
         q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -129,7 +132,7 @@ def _sdpa_blocked(q, k, v, q_pos, kv_pos, causal: bool, block_q: int,
 
     def body(_, xs):
         qi, pi = xs
-        oi = _sdpa_naive(qi, k, v, pi, kv_pos, causal, mixed)
+        oi = _sdpa_naive(qi, k, v, pi, kv_pos, causal, mixed, scale)
         return None, oi
 
     _, ob = jax.lax.scan(body, None, (qb, pb), unroll=True if unroll else 1)
@@ -140,8 +143,10 @@ def _sdpa_blocked(q, k, v, q_pos, kv_pos, causal: bool, block_q: int,
 def sdpa(cfg, q, k, v, q_pos, kv_pos, causal: bool):
     if cfg.attn_impl == "blocked" and causal:
         return _sdpa_blocked(q, k, v, q_pos, kv_pos, causal, cfg.attn_block_q,
-                             unroll=cfg.unroll_blocks, mixed=cfg.attn_mixed)
-    return _sdpa_naive(q, k, v, q_pos, kv_pos, causal, cfg.attn_mixed)
+                             unroll=cfg.unroll_blocks, mixed=cfg.attn_mixed,
+                             scale=cfg.attn_scale)
+    return _sdpa_naive(q, k, v, q_pos, kv_pos, causal, cfg.attn_mixed,
+                       cfg.attn_scale)
 
 
 # ---------------------------------------------------------------------------
@@ -158,14 +163,16 @@ def self_attention(cfg, p, x, positions, *, rope: bool = True, causal: bool = Tr
 
 def self_attention_prefill(cfg, p, x, positions, cache_len: int, *, rope: bool = True):
     """Causal self-attention that also builds the KV cache (padded to
-    cache_len).  Returns (out, cache)."""
+    cache_len).  Returns (out, cache).  The attention core and the cache's
+    padding run under the scope ``avec.sdpa``, the projections outside."""
     B, S, _ = x.shape
     q = _project_q(cfg, p, x, positions, rope)
     k, v = _project_kv(cfg, p, x, positions, rope)
-    o = sdpa(cfg, q, k, v, positions, positions, causal=True)
-    pad = cache_len - S
-    kc = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    vc = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    with jax.named_scope("avec.sdpa"):
+        o = sdpa(cfg, q, k, v, positions, positions, causal=True)
+        pad = cache_len - S
+        kc = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        vc = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     return _out_proj(p, o), {"k": kc, "v": vc}
 
 
@@ -175,7 +182,9 @@ def self_attention_decode(cfg, p, x, cache, layer, pos, *, rope: bool = True):
     () int32 shared write index, or (B,) per-row indices (continuous
     batching).  Returns (out, the stacked k/v with this token written at
     (layer, :, pos)): the write is one token, so a cache carried through
-    the layer scan and donated is updated in place, never copied whole."""
+    the layer scan and donated is updated in place, never copied whole.
+    The one-token K/V write, the K/V read and the attention core run under
+    the scope ``avec.sdpa``, the projections outside."""
     B = x.shape[0]
     S_max = cache["k"].shape[2]
     pos = jnp.asarray(pos, jnp.int32)
@@ -183,20 +192,25 @@ def self_attention_decode(cfg, p, x, cache, layer, pos, *, rope: bool = True):
     positions = pos[:, None] if per_row else jnp.full((B, 1), pos, jnp.int32)
     q = _project_q(cfg, p, x, positions, rope)
     k_new, v_new = _project_kv(cfg, p, x, positions, rope)
-    if per_row:
-        rows = jnp.arange(B)
-        kc = cache["k"].at[layer, rows, pos].set(k_new[:, 0].astype(cache["k"].dtype))
-        vc = cache["v"].at[layer, rows, pos].set(v_new[:, 0].astype(cache["v"].dtype))
-    else:
-        at = (layer, 0, pos, 0, 0)
-        kc = jax.lax.dynamic_update_slice(
-            cache["k"], k_new[None].astype(cache["k"].dtype), at)
-        vc = jax.lax.dynamic_update_slice(
-            cache["v"], v_new[None].astype(cache["v"].dtype), at)
-    k = jax.lax.dynamic_index_in_dim(kc, layer, 0, keepdims=False)
-    v = jax.lax.dynamic_index_in_dim(vc, layer, 0, keepdims=False)
-    kv_pos = jnp.broadcast_to(jnp.arange(S_max, dtype=jnp.int32)[None], (B, S_max))
-    o = _sdpa_naive(q, k, v, positions, kv_pos, causal=True, mixed=cfg.attn_mixed)
+    with jax.named_scope("avec.sdpa"):
+        if per_row:
+            rows = jnp.arange(B)
+            kc = cache["k"].at[layer, rows, pos].set(
+                k_new[:, 0].astype(cache["k"].dtype))
+            vc = cache["v"].at[layer, rows, pos].set(
+                v_new[:, 0].astype(cache["v"].dtype))
+        else:
+            at = (layer, 0, pos, 0, 0)
+            kc = jax.lax.dynamic_update_slice(
+                cache["k"], k_new[None].astype(cache["k"].dtype), at)
+            vc = jax.lax.dynamic_update_slice(
+                cache["v"], v_new[None].astype(cache["v"].dtype), at)
+        k = jax.lax.dynamic_index_in_dim(kc, layer, 0, keepdims=False)
+        v = jax.lax.dynamic_index_in_dim(vc, layer, 0, keepdims=False)
+        kv_pos = jnp.broadcast_to(jnp.arange(S_max, dtype=jnp.int32)[None],
+                                  (B, S_max))
+        o = _sdpa_naive(q, k, v, positions, kv_pos, causal=True,
+                        mixed=cfg.attn_mixed, scale=cfg.attn_scale)
     return _out_proj(p, o), {"k": kc, "v": vc}
 
 
@@ -211,7 +225,8 @@ def cross_attention(cfg, p, x, context):
     zeros_k = jnp.zeros((B, context.shape[1]), jnp.int32)
     q = _project_q(cfg, p, x, zeros_q, rope=False)
     k, v = _project_kv(cfg, p, context, zeros_k, rope=False)
-    o = _sdpa_naive(q, k, v, None, None, causal=False, mixed=cfg.attn_mixed)
+    o = _sdpa_naive(q, k, v, None, None, causal=False, mixed=cfg.attn_mixed,
+                    scale=cfg.attn_scale)
     return _out_proj(p, o)
 
 
@@ -224,7 +239,8 @@ def cross_attention_cached(cfg, p, x, cache, layer):
     q = _project_q(cfg, p, x, zeros_q, rope=False)
     k, v = (jax.lax.dynamic_index_in_dim(cache[n], layer, 0, keepdims=False)
             for n in ("cross_k", "cross_v"))
-    o = _sdpa_naive(q, k, v, None, None, causal=False, mixed=cfg.attn_mixed)
+    o = _sdpa_naive(q, k, v, None, None, causal=False, mixed=cfg.attn_mixed,
+                    scale=cfg.attn_scale)
     return _out_proj(p, o)
 
 

@@ -15,6 +15,10 @@ Prefill builds them per block and the scan stacks them.  Decode carries the
 stacked cache through the scan instead: each layer reads its slice at the
 block index and writes back only what it changes (one token of K/V; the
 whole SSM state), so a donated cache is updated in place.
+
+Each layer also returns ``aux``: in training the MoE load-balancing loss;
+in prefill and decode the MoE layer's counts (``moe.moe_serve``), int32
+(rows routed to held experts, held experts hit), zero without an MoE.
 """
 from __future__ import annotations
 
@@ -25,7 +29,7 @@ from repro.models import attention as attn
 from repro.models import mamba as mb
 from repro.models.layers import norm_specs, apply_norm
 from repro.models.mlp import mlp_specs, apply_mlp
-from repro.models.moe import moe_specs, apply_moe
+from repro.models.moe import moe_specs, apply_moe, moe_serve
 from repro.models.params import ParamSpec
 
 
@@ -109,18 +113,31 @@ def stacked_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16) -> dict:
 # Apply
 # ---------------------------------------------------------------------------
 
+def _no_aux(mode: str):
+    """A layer's ``aux`` when it adds none (see the module docstring)."""
+    if mode == "train":
+        return jnp.zeros((), jnp.float32)
+    return jnp.zeros((2,), jnp.int32)
+
+
+def _residual(cfg, h, y):
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return h + y
+
+
 def _apply_layer(cfg, j: int, p: dict, h, *, positions, mode: str,
                  cache: dict | None, pos, layer, context):
-    """One layer.  Returns (h, new_cache, aux_loss).  In decode, ``cache``
+    """One layer.  Returns (h, new_cache, aux).  In decode, ``cache``
     holds this layer's leaves stacked over blocks and ``layer`` is the
     block index; the new cache is the stacked leaves, updated."""
-    aux = jnp.zeros((), jnp.float32)
+    aux = _no_aux(mode)
     new_cache: dict = {}
     kind = "attn" if "attn" in p else "mamba"
 
     # ---- token mixer -------------------------------------------------------
     normed = apply_norm(cfg, p["mixer_norm"], h)
-    rope = cfg.family != "encdec"
+    rope = cfg.rope and cfg.family != "encdec"
     if kind == "attn":
         if mode == "train":
             mix = attn.self_attention(cfg, p["attn"], normed, positions, rope=rope)
@@ -152,7 +169,7 @@ def _apply_layer(cfg, j: int, p: dict, h, *, positions, mode: str,
         # cross/moe never combined with parallel_block in assigned archs
         return h, new_cache, aux
 
-    h = h + mix
+    h = _residual(cfg, h, mix)
 
     # ---- gated cross-attention (VLM) ---------------------------------------
     if "cross" in p:
@@ -171,22 +188,23 @@ def _apply_layer(cfg, j: int, p: dict, h, *, positions, mode: str,
     # ---- FFN ----------------------------------------------------------------
     if "moe" in p:
         fn = apply_norm(cfg, p["ffn_norm"], h)
-        y, moe_aux = apply_moe(cfg, p["moe"], fn)
-        h = h + y
-        aux = aux + moe_aux
+        with jax.named_scope("avec.moe"):
+            y, aux = (apply_moe if mode == "train" else moe_serve)(
+                cfg, p["moe"], fn)
+        h = _residual(cfg, h, y)
     elif "mlp" in p:
         fn = apply_norm(cfg, p["ffn_norm"], h)
-        h = h + apply_mlp(cfg, p["mlp"], fn)
+        h = _residual(cfg, h, apply_mlp(cfg, p["mlp"], fn))
 
     return h, new_cache, aux
 
 
 def apply_block(cfg, p: dict, h, *, positions, mode: str, cache: dict | None,
                 pos=None, layer=None, context=None):
-    """Apply one block (list of layers).  Returns (h, new_cache, aux).  In
-    decode ``cache`` is the whole stacked cache and ``layer`` the block's
-    index into it."""
-    aux = jnp.zeros((), jnp.float32)
+    """Apply one block (list of layers).  Returns (h, new_cache, aux), aux
+    summed over the layers.  In decode ``cache`` is the whole stacked cache
+    and ``layer`` the block's index into it."""
+    aux = _no_aux(mode)
     new_layers = []
     for j, lp in enumerate(p["layers"]):
         lcache = cache["layers"][j] if cache is not None else None

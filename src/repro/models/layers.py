@@ -18,7 +18,8 @@ def norm_specs(cfg) -> dict:
             "bias": ParamSpec((cfg.d_model,), ("embed",), "zeros", dtype=jnp.float32)}
 
 
-def apply_norm(cfg, p, x, eps: float = 1e-6):
+def apply_norm(cfg, p, x):
+    eps = cfg.norm_eps
     xf = x.astype(jnp.float32)
     if cfg.norm == "rmsnorm":
         var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
@@ -44,17 +45,23 @@ def embed_specs(cfg) -> dict:
 
 
 def embed_tokens(cfg, p, tokens):
-    return p["tok"][tokens].astype(jnp.dtype(cfg.compute_dtype))
+    h = p["tok"][tokens].astype(jnp.dtype(cfg.compute_dtype))
+    if cfg.embedding_multiplier != 1.0:
+        h = h * cfg.embedding_multiplier
+    return h
 
 
 def unembed(cfg, p, h):
-    """Project to padded-vocab logits; pad region masked to -inf so softmax /
-    sampling are exact over the real vocab."""
+    """Project to padded-vocab logits (divided by ``logits_scaling``); pad
+    region masked to -inf so softmax / sampling are exact over the real
+    vocab."""
     if cfg.tie_embeddings:
         logits = jnp.einsum("bsd,vd->bsv", h, p["tok"].astype(h.dtype))
     else:
         logits = jnp.einsum("bsd,dv->bsv", h, p["head"].astype(h.dtype))
     logits = logits.astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     pad_mask = jnp.arange(cfg.padded_vocab) >= cfg.vocab_size
     return jnp.where(pad_mask[None, None, :], -1e30, logits)
 

@@ -67,7 +67,7 @@ def _conv_step(window, w, b):
     return jax.nn.silu(out + b.astype(jnp.float32)).astype(window.dtype)
 
 
-def _gated_norm(y, z, scale, eps=1e-6):
+def _gated_norm(y, z, scale, eps):
     """Mamba2 gated RMSNorm: rmsnorm(y * silu(z)) * scale."""
     yf = (y * jax.nn.silu(z.astype(jnp.float32))).astype(jnp.float32)
     var = jnp.mean(jnp.square(yf), axis=-1, keepdims=True)
@@ -112,7 +112,7 @@ def mamba_forward(cfg, p, x, *, return_cache: bool = False):
     y, final_state = ssd_chunked(xh, dt, A, Bh, Ch, ssm.chunk)
     y = y + (p["D"][None, None, :, None] * xh.astype(jnp.float32)).astype(y.dtype)
     y = y.reshape(B_, S, di)
-    y = _gated_norm(y, z, p["norm_scale"])
+    y = _gated_norm(y, z, p["norm_scale"], cfg.norm_eps)
     out = y @ p["wo"].astype(y.dtype)
 
     if not return_cache:
@@ -155,7 +155,7 @@ def mamba_decode(cfg, p, x, cache):
     y_t = y_t + (p["D"][None, :, None] * xr_t.reshape(B_, nh, hd).astype(jnp.float32)
                  ).astype(y_t.dtype)
     y = y_t.reshape(B_, 1, di)
-    y = _gated_norm(y, z, p["norm_scale"])
+    y = _gated_norm(y, z, p["norm_scale"], cfg.norm_eps)
     out = y @ p["wo"].astype(y.dtype)
     return out, {"conv": new_conv, "ssm": new_state}
 

@@ -9,6 +9,8 @@ Functions (all pure; cfg is static):
   loss_fn(cfg, params, batch)            -> (loss, metrics)
   prefill(cfg, params, batch, cache_len) -> (last_logits, cache)
   decode_step(cfg, params, cache, batch) -> (logits, cache)
+    (either with ``expert_counts=True`` adds the MoE layers' counts: int32
+    (rows routed to held experts, held experts hit), summed over layers)
   init_cache(cfg, batch, max_len)        -> cache pytree
   input_specs(cfg, shape)                -> ShapeDtypeStruct batch (dry-run)
 
@@ -102,6 +104,8 @@ def _xent_chunked(cfg, params, h, targets):
         else:
             Wc = jax.lax.dynamic_slice_in_dim(W, c0, ck, 1).astype(jnp.float32)
             lg = jnp.einsum("bsd,dv->bsv", hf, Wc)
+        if cfg.logits_scaling != 1.0:
+            lg = lg / cfg.logits_scaling
         col = c0 + jnp.arange(ck)
         lg = jnp.where((col >= cfg.vocab_size)[None, None, :], -1e30, lg)
         mc = jnp.max(lg, axis=-1)
@@ -137,29 +141,35 @@ def loss_fn(cfg, params, batch):
 # Serving paths
 # ---------------------------------------------------------------------------
 
-def prefill(cfg, params, batch, cache_len: int, cache_dtype=jnp.bfloat16):
-    """Returns (last-token logits (B,1,V), cache)."""
+def prefill(cfg, params, batch, cache_len: int, cache_dtype=jnp.bfloat16,
+            *, expert_counts: bool = False):
+    """Returns (last-token logits (B,1,V), cache[, MoE counts])."""
+    counts = jnp.zeros((2,), jnp.int32)
     if cfg.family == "encdec":
         enc = ed.encode(cfg, params, batch["frames"])
         h, cache = ed.dec_prefill(cfg, params, batch["tokens"], enc, cache_len,
                                   cache_dtype)
     else:
-        h, cache = tf.lm_prefill(cfg, params, batch["tokens"], cache_len,
-                                 context=_context(cfg, batch),
-                                 cache_dtype=cache_dtype)
+        h, cache, counts = tf.lm_prefill(cfg, params, batch["tokens"],
+                                         cache_len,
+                                         context=_context(cfg, batch),
+                                         cache_dtype=cache_dtype)
     lg = logits_from_hidden(cfg, params, h[:, -1:])
-    return lg, cache
+    return (lg, cache, counts) if expert_counts else (lg, cache)
 
 
-def decode_step(cfg, params, cache, batch):
+def decode_step(cfg, params, cache, batch, *, expert_counts: bool = False):
     """batch: {"tokens": (B,1), "pos": ()} (+ "vision" context for vlm).
-    Returns (logits (B,1,V), new cache)."""
+    Returns (logits (B,1,V), new cache[, MoE counts])."""
+    counts = jnp.zeros((2,), jnp.int32)
     if cfg.family == "encdec":
         h, cache = ed.dec_step(cfg, params, cache, batch["tokens"], batch["pos"])
     else:
-        h, cache = tf.lm_decode_step(cfg, params, cache, batch["tokens"],
-                                     batch["pos"], context=_context(cfg, batch))
-    return logits_from_hidden(cfg, params, h), cache
+        h, cache, counts = tf.lm_decode_step(cfg, params, cache,
+                                             batch["tokens"], batch["pos"],
+                                             context=_context(cfg, batch))
+    lg = logits_from_hidden(cfg, params, h)
+    return (lg, cache, counts) if expert_counts else (lg, cache)
 
 
 def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):

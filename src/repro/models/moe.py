@@ -22,8 +22,19 @@ Two dispatch scopes, selected by ``cfg.moe_sharded_dispatch``:
 
 Expert weights carry the "experts" logical axis and shard over the model mesh
 axis (expert parallelism).
+
+A layer may hold only some of the router's experts (``MoEConfig.held``,
+``first_held``): as one chip of an expert-parallel deployment it routes over
+all of them and computes its own experts' part of the result, and what the
+other experts would add is left out.
+
+Serving (prefill and decode) uses ``moe_serve``: no capacity and no dropped
+token, the work bounded by the rows routed to the held experts (a grouped
+matmul over rows sorted by expert).  ``apply_moe`` is the training path.
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -34,15 +45,15 @@ from repro.models.params import ParamSpec
 
 def moe_specs(cfg) -> dict:
     m = cfg.moe
-    d, E, f = cfg.d_model, m.num_experts, m.d_ff
+    d, E, H, f = cfg.d_model, m.num_experts, m.n_held, m.d_ff
     specs = {
         "router": ParamSpec((d, E), ("embed", None), "normal", d ** -0.5,
                             dtype=jnp.float32),
-        "w_gate": ParamSpec((E, d, f), ("experts", "embed", "expert_mlp"),
+        "w_gate": ParamSpec((H, d, f), ("experts", "embed", "expert_mlp"),
                             "normal", d ** -0.5),
-        "w_up": ParamSpec((E, d, f), ("experts", "embed", "expert_mlp"),
+        "w_up": ParamSpec((H, d, f), ("experts", "embed", "expert_mlp"),
                           "normal", d ** -0.5),
-        "w_down": ParamSpec((E, f, d), ("experts", "expert_mlp", "embed"),
+        "w_down": ParamSpec((H, f, d), ("experts", "expert_mlp", "embed"),
                             "normal", f ** -0.5),
     }
     if m.dense_residual:
@@ -52,7 +63,7 @@ def moe_specs(cfg) -> dict:
 
 def _capacity(cfg, n_tokens: int) -> int:
     m = cfg.moe
-    c = int(n_tokens * m.top_k / m.num_experts * m.capacity_factor)
+    c = math.ceil(n_tokens * m.top_k / m.num_experts * m.capacity_factor)
     return max(8, -(-c // 8) * 8)  # round up to 8, floor 8
 
 
@@ -68,7 +79,9 @@ def _constrain(x, *entries):
 
 
 def apply_moe(cfg, p, x):
-    """x: (B, S, d) -> (out (B, S, d), aux_loss scalar)."""
+    """x: (B, S, d) -> (out (B, S, d), aux_loss scalar).  Training path
+    over a layer that holds every expert: per-expert capacity, assignments
+    past it dropped."""
     m = cfg.moe
     B, S, d = x.shape
     E, k = m.num_experts, m.top_k
@@ -140,3 +153,80 @@ def apply_moe(cfg, p, x):
     if m.dense_residual:
         y = y + apply_mlp(cfg, p["dense"], xg)
     return y.reshape(B, S, d), aux
+
+
+def _grouped_matmul(x, w, sizes, out_dtype):
+    """x (R, k) rows sorted by group, w (G, k, n), sizes (G,) -> (R, n):
+    row r times the matrix of its group.  Rows past ``sizes.sum()`` are
+    left undefined.  On a TPU the Pallas grouped matmul (megablox), which
+    skips empty groups and keeps the enclosing scope's name on its
+    operation (XLA's expansion of ``ragged_dot`` there drops it, so no
+    scope could time the expert matmuls); elsewhere ``ragged_dot``."""
+    if jax.default_backend() != "tpu":
+        return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=out_dtype)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    R, k = x.shape
+    tiling = (min(R, _GMM_TILE_M), _tile(k), _tile(w.shape[2]))
+    return gmm(x, w, sizes, preferred_element_type=out_dtype, tiling=tiling)
+
+
+_GMM_TILE_M = 512
+
+
+def _tile(n: int) -> int:
+    """The grouped matmul's tile along a weight dimension of size n."""
+    return n if n <= 768 else 512
+
+
+def _rows(n: int) -> int:
+    """Row count of the grouped matmuls for n assignments: a multiple of
+    the grouped matmul's row tile (16 rows below one tile)."""
+    if n <= _GMM_TILE_M:
+        return -(-n // 16) * 16
+    return -(-n // _GMM_TILE_M) * _GMM_TILE_M
+
+
+def moe_serve(cfg, p, x):
+    """Serving path.  x: (B, S, d) -> (out (B, S, d), counts int32 (2,):
+    the rows routed to held experts and the held experts hit).
+
+    Routing in float32: the top-k router logits, gated by their softmax.
+    Every assignment to a held expert is computed (no capacity, no dropped
+    token): assignments sorted by local expert id, the held ones first, feed
+    one grouped matmul (``_grouped_matmul``) per projection over at most
+    T * min(k, held) rows (rounded up to the matmul's row tile); each output
+    row is scaled by its gate and added back to its token in float32."""
+    m = cfg.moe
+    B, S, d = x.shape
+    k, H = m.top_k, m.n_held
+    T = B * S
+    xt = x.reshape(T, d)
+
+    logits = xt.astype(jnp.float32) @ p["router"]              # (T,E)
+    top_l, top_e = jax.lax.top_k(logits, k)                    # (T,k)
+    gates = jax.nn.softmax(top_l, axis=-1).reshape(T * k)
+
+    local = (top_e - m.first_held).reshape(T * k)
+    group = jnp.where((local >= 0) & (local < H), local, H)    # H: not held
+    R = _rows(T * min(k, H))   # the most assignments held experts can get
+    group = jnp.pad(group, (0, max(R - T * k, 0)), constant_values=H)
+    order = jnp.argsort(group, stable=True)[:R]
+    sizes = jnp.zeros((H,), jnp.int32).at[group].add(1, mode="drop")
+    valid = group[order] < H
+    tok = jnp.minimum(order // k, T - 1)
+    rows = xt[tok]                                             # (R,d)
+
+    dt = x.dtype
+    g_ = _grouped_matmul(rows, p["w_gate"].astype(dt), sizes, dt)
+    u_ = _grouped_matmul(rows, p["w_up"].astype(dt), sizes, dt)
+    h = jax.nn.silu(g_) * u_
+    out = _grouped_matmul(h, p["w_down"].astype(dt), sizes, jnp.float32)
+    w = jnp.where(valid, gates[jnp.minimum(order, T * k - 1)], 0.0)
+    contrib = jnp.where(valid[:, None], out, 0.0) * w[:, None]
+    y = jnp.zeros((T, d), jnp.float32).at[tok].add(contrib)
+    y = y.astype(dt)
+
+    if m.dense_residual:
+        y = y + apply_mlp(cfg, p["dense"], xt)
+    counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0, dtype=jnp.int32)])
+    return y.reshape(B, S, d), counts

@@ -48,7 +48,9 @@ def lm_hidden(cfg, params, tokens, *, context=None):
 
 def lm_prefill(cfg, params, tokens, cache_len: int, *, context=None,
                cache_dtype=jnp.bfloat16):
-    """Prefill: returns (h (B,S,d), stacked cache)."""
+    """Prefill: returns (h (B,S,d), stacked cache, the MoE layers' counts
+    summed over layers: int32 (rows routed to held experts, held experts
+    hit))."""
     B, S = tokens.shape
     positions = _positions(tokens)
     h = embed_tokens(cfg, params["embed"], tokens)
@@ -57,21 +59,21 @@ def lm_prefill(cfg, params, tokens, cache_len: int, *, context=None,
     def body(carry, xs):
         hh = carry
         bp, bc = xs
-        hh, nc, _ = apply_block(cfg, bp, hh, positions=positions, mode="prefill",
-                                cache=bc, context=context)
-        return hh, nc
+        hh, nc, counts = apply_block(cfg, bp, hh, positions=positions,
+                                     mode="prefill", cache=bc, context=context)
+        return hh, (nc, counts)
 
-    h, cache = jax.lax.scan(body, h, (params["blocks"], init),
-                            unroll=True if cfg.unroll_blocks else 1)
+    h, (cache, counts) = jax.lax.scan(body, h, (params["blocks"], init),
+                                      unroll=True if cfg.unroll_blocks else 1)
     h = apply_norm(cfg, params["final_norm"], h)
-    return h, cache
+    return h, cache, jnp.sum(counts, axis=0)
 
 
 def lm_decode_step(cfg, params, cache, tokens, pos, *, context=None):
     """One-token decode.  tokens: (B,1); pos: () shared or (B,) per-row int32.
-    Returns (h, cache).  The stacked cache rides in the scan's carry and each
-    block writes only what it changes into it, so with the cache donated
-    the step updates it in place."""
+    Returns (h, cache, MoE counts as ``lm_prefill``).  The stacked cache
+    rides in the scan's carry and each block writes only what it changes
+    into it, so with the cache donated the step updates it in place."""
     B = tokens.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
     positions = pos[:, None] if pos.ndim == 1 else jnp.full((B, 1), pos, jnp.int32)
@@ -80,15 +82,16 @@ def lm_decode_step(cfg, params, cache, tokens, pos, *, context=None):
     def body(carry, xs):
         hh, cc = carry
         i, bp = xs
-        hh, cc, _ = apply_block(cfg, bp, hh, positions=positions, mode="decode",
-                                cache=cc, pos=pos, layer=i, context=context)
-        return (hh, cc), None
+        hh, cc, counts = apply_block(cfg, bp, hh, positions=positions,
+                                     mode="decode", cache=cc, pos=pos, layer=i,
+                                     context=context)
+        return (hh, cc), counts
 
-    (h, cache), _ = jax.lax.scan(
+    (h, cache), counts = jax.lax.scan(
         body, (h, cache), (jnp.arange(num_blocks(cfg)), params["blocks"]),
         unroll=True if cfg.unroll_blocks else 1)
     h = apply_norm(cfg, params["final_norm"], h)
-    return h, cache
+    return h, cache, jnp.sum(counts, axis=0)
 
 
 def lm_init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
