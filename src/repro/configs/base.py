@@ -96,14 +96,6 @@ class ModelConfig:
     compute_dtype: str = "bfloat16"
     optimizer: str = "adamw"       # adamw | adafactor (big archs)
     remat: bool = True
-    # perf knobs (hillclimb levers; defaults are the paper-faithful baseline)
-    attn_impl: str = "naive"       # naive | blocked
-    attn_block_q: int = 512
-    attn_mixed: bool = False       # bf16 operands + fp32 accumulation
-    moe_sharded_dispatch: bool = False  # sharding hints on the MoE buffers
-    xent_impl: str = "full"        # full | chunked
-    xent_chunk: int = 8192
-    sharding_profile: str = "dp_tp"  # dp_tp | fsdp_tp
     # Dry-run cost-exactness: XLA's cost_analysis does not multiply while-loop
     # trip counts, so the dry-run fully unrolls the structural scans (HLO gets
     # big; costs get exact).  Runtime paths keep the rolled scans.

@@ -112,7 +112,6 @@ def make_model_library(cfg, max_cache_len: int = 256) -> Library:
     @functools.partial(jax.jit, static_argnames=())
     def _prefill(params, batch):
         return M.prefill(cfg, params, batch, max_cache_len,
-                         cache_dtype=jnp.float32,
                          expert_counts=counts is not None)
 
     _decode = decode_jit(cfg, expert_counts=counts is not None)

@@ -15,8 +15,7 @@ Profiles:
 Divisibility policy: a dimension shards over an axis group only when the
 group size divides it exactly (jit in_shardings reject uneven shards) —
 minicpm's 36 heads, arctic's 56 heads and mamba2's 24 SSD heads therefore
-replicate over "model" in the baseline; resharding those is a hillclimb
-lever (see EXPERIMENTS.md §Perf).
+replicate over "model" in the baseline.
 """
 from __future__ import annotations
 

@@ -26,7 +26,7 @@ Usage:
   python -m repro.launch.dryrun --arch granite-3-2b --shape train_4k
   python -m repro.launch.dryrun --all            # every applicable cell
   python -m repro.launch.dryrun --all --mesh multi
-  ... [--profile fsdp_tp] [--attn-impl blocked] [--xent-impl chunked] [--tag x]
+  ... [--profile fsdp_tp] [--remat off] [--exact] [--tag x]
 """
 import argparse
 import dataclasses
@@ -183,7 +183,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, profile: str,
         else:
             # quadratic fit over k in {1,2,4} blocks; validated against the
             # fully-unrolled granite-3-2b/train_4k cell: flops within 3%,
-            # bytes within 8%, collectives exact (see EXPERIMENTS.md §Dry-run)
+            # bytes within 8%, collectives exact
             t0 = time.perf_counter()
             ks = (1, 2, 4)
             cs = [_costs_of(_compile_cell(
@@ -248,12 +248,7 @@ def main() -> None:
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--profile", default="dp_tp",
                     choices=["dp_tp", "fsdp_tp", "dp_tp_hd", "fsdp_tp_hd"])
-    ap.add_argument("--attn-impl", default=None, choices=["naive", "blocked"])
-    ap.add_argument("--xent-impl", default=None, choices=["full", "chunked"])
-    ap.add_argument("--attn-block-q", type=int, default=None)
     ap.add_argument("--remat", default=None, choices=["on", "off"])
-    ap.add_argument("--attn-mixed", action="store_true")
-    ap.add_argument("--moe-sharded", action="store_true")
     ap.add_argument("--exact", action="store_true",
                     help="fully unroll for cost analysis (slow cross-check)")
     ap.add_argument("--tag", default="")
@@ -261,18 +256,8 @@ def main() -> None:
     args = ap.parse_args()
 
     overrides: dict = {}
-    if args.attn_impl:
-        overrides["attn_impl"] = args.attn_impl
-    if args.xent_impl:
-        overrides["xent_impl"] = args.xent_impl
-    if args.attn_block_q:
-        overrides["attn_block_q"] = args.attn_block_q
     if args.remat:
         overrides["remat"] = args.remat == "on"
-    if args.attn_mixed:
-        overrides["attn_mixed"] = True
-    if args.moe_sharded:
-        overrides["moe_sharded_dispatch"] = True
 
     archs = [args.arch] if args.arch else list_archs()
     shapes = [args.shape] if args.shape else list(SHAPES)

@@ -1,12 +1,5 @@
-"""GQA attention: train / prefill / decode paths.
-
-Two implementations, selected by ``cfg.attn_impl``:
-
-* ``naive``   — materializes the full (B, H, S, T) score tensor (the
-  paper-faithful simple baseline).
-* ``blocked`` — lax.scan over query blocks; peak activation memory drops by
-  S/block_q (flash-style memory behaviour in pure jnp; the Pallas kernel in
-  ``repro.kernels.flash_attention`` is the TPU-native version of this path).
+"""GQA attention: train / prefill / decode paths over one core, ``sdpa``,
+which upcasts Q/K/V to float32 and materializes the whole score tensor.
 
 KV caches are plain pytrees: {"k": (B, S_max, K, hd), "v": (B, S_max, K, hd)};
 decode reads and writes them stacked over layers, (L, B, S_max, K, hd).
@@ -80,73 +73,23 @@ def _out_proj(p, o):
 # Core scaled-dot-product attention (GQA), mask by positions
 # ---------------------------------------------------------------------------
 
-def _sdpa_naive(q, k, v, q_pos, kv_pos, causal: bool, mixed: bool = False,
-                scale: float = 0.0):
+def sdpa(q, k, v, q_pos, kv_pos, causal: bool, scale: float = 0.0):
     """q: (B,S,H,hd); k/v: (B,T,K,hd); q_pos: (B,S) | None; kv_pos: (B,T) | None.
-    Scores are scaled by ``scale`` (0: hd ** -0.5).
-
-    ``mixed=False`` (paper-faithful baseline): upcast operands to fp32 before
-    the score/value matmuls — simple but doubles the bytes moved for bf16
-    KV.  ``mixed=True`` (hillclimb lever ``cfg.attn_mixed``): keep operands
-    in their storage dtype and accumulate in fp32 via
-    ``preferred_element_type`` — same numerics for the reduction, half the
-    HBM traffic on the KV read path."""
+    Scores and the weighted sum over values are float32; scores are scaled
+    by ``scale`` (0: hd ** -0.5).  Returns (B,S,H,hd) in q's dtype."""
     B, S, H, hd = q.shape
-    T, K = k.shape[1], k.shape[2]
+    K = k.shape[2]
     G = H // K
     scale = scale or hd ** -0.5
-    if mixed:
-        qr = q.reshape(B, S, K, G, hd)
-        scores = jnp.einsum("bskgh,btkh->bkgst", qr, k,
-                            preferred_element_type=jnp.float32) * scale
-    else:
-        qr = q.reshape(B, S, K, G, hd).astype(jnp.float32)
-        scores = jnp.einsum("bskgh,btkh->bkgst", qr,
-                            k.astype(jnp.float32)) * scale   # (B,K,G,S,T)
+    qr = q.reshape(B, S, K, G, hd).astype(jnp.float32)
+    scores = jnp.einsum("bskgh,btkh->bkgst", qr,
+                        k.astype(jnp.float32)) * scale       # (B,K,G,S,T)
     if causal:
         mask = kv_pos[:, None, :] <= q_pos[:, :, None]        # (B,S,T)
         scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
-    if mixed:
-        o = jnp.einsum("bkgst,btkh->bskgh", probs.astype(q.dtype), v,
-                       preferred_element_type=jnp.float32)
-    else:
-        o = jnp.einsum("bkgst,btkh->bskgh", probs, v.astype(jnp.float32))
+    o = jnp.einsum("bkgst,btkh->bskgh", probs, v.astype(jnp.float32))
     return o.reshape(B, S, H, hd).astype(q.dtype)
-
-
-def _sdpa_blocked(q, k, v, q_pos, kv_pos, causal: bool, block_q: int,
-                  unroll: bool = False, mixed: bool = False,
-                  scale: float = 0.0):
-    """lax.scan over query blocks: peak score memory B*K*G*block_q*T."""
-    B, S, H, hd = q.shape
-    if S <= block_q:
-        return _sdpa_naive(q, k, v, q_pos, kv_pos, causal, mixed, scale)
-    pad = (-S) % block_q
-    if pad:
-        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        q_pos = jnp.pad(q_pos, ((0, 0), (0, pad)), constant_values=-1)
-    nb = q.shape[1] // block_q
-    qb = q.reshape(B, nb, block_q, H, hd).transpose(1, 0, 2, 3, 4)
-    pb = q_pos.reshape(B, nb, block_q).transpose(1, 0, 2)
-
-    def body(_, xs):
-        qi, pi = xs
-        oi = _sdpa_naive(qi, k, v, pi, kv_pos, causal, mixed, scale)
-        return None, oi
-
-    _, ob = jax.lax.scan(body, None, (qb, pb), unroll=True if unroll else 1)
-    o = ob.transpose(1, 0, 2, 3, 4).reshape(B, nb * block_q, H, hd)
-    return o[:, :S]
-
-
-def sdpa(cfg, q, k, v, q_pos, kv_pos, causal: bool):
-    if cfg.attn_impl == "blocked" and causal:
-        return _sdpa_blocked(q, k, v, q_pos, kv_pos, causal, cfg.attn_block_q,
-                             unroll=cfg.unroll_blocks, mixed=cfg.attn_mixed,
-                             scale=cfg.attn_scale)
-    return _sdpa_naive(q, k, v, q_pos, kv_pos, causal, cfg.attn_mixed,
-                       cfg.attn_scale)
 
 
 # ---------------------------------------------------------------------------
@@ -157,7 +100,7 @@ def self_attention(cfg, p, x, positions, *, rope: bool = True, causal: bool = Tr
     """Full self-attention (train path; bidirectional for encoders).  x: (B,S,d)."""
     q = _project_q(cfg, p, x, positions, rope)
     k, v = _project_kv(cfg, p, x, positions, rope)
-    o = sdpa(cfg, q, k, v, positions, positions, causal=causal)
+    o = sdpa(q, k, v, positions, positions, causal, cfg.attn_scale)
     return _out_proj(p, o)
 
 
@@ -169,7 +112,7 @@ def self_attention_prefill(cfg, p, x, positions, cache_len: int, *, rope: bool =
     q = _project_q(cfg, p, x, positions, rope)
     k, v = _project_kv(cfg, p, x, positions, rope)
     with jax.named_scope("avec.sdpa"):
-        o = sdpa(cfg, q, k, v, positions, positions, causal=True)
+        o = sdpa(q, k, v, positions, positions, True, cfg.attn_scale)
         pad = cache_len - S
         kc = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         vc = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -209,8 +152,7 @@ def self_attention_decode(cfg, p, x, cache, layer, pos, *, rope: bool = True):
         v = jax.lax.dynamic_index_in_dim(vc, layer, 0, keepdims=False)
         kv_pos = jnp.broadcast_to(jnp.arange(S_max, dtype=jnp.int32)[None],
                                   (B, S_max))
-        o = _sdpa_naive(q, k, v, positions, kv_pos, causal=True,
-                        mixed=cfg.attn_mixed, scale=cfg.attn_scale)
+        o = sdpa(q, k, v, positions, kv_pos, True, cfg.attn_scale)
     return _out_proj(p, o), {"k": kc, "v": vc}
 
 
@@ -225,8 +167,7 @@ def cross_attention(cfg, p, x, context):
     zeros_k = jnp.zeros((B, context.shape[1]), jnp.int32)
     q = _project_q(cfg, p, x, zeros_q, rope=False)
     k, v = _project_kv(cfg, p, context, zeros_k, rope=False)
-    o = _sdpa_naive(q, k, v, None, None, causal=False, mixed=cfg.attn_mixed,
-                    scale=cfg.attn_scale)
+    o = sdpa(q, k, v, None, None, False, cfg.attn_scale)
     return _out_proj(p, o)
 
 
@@ -239,8 +180,7 @@ def cross_attention_cached(cfg, p, x, cache, layer):
     q = _project_q(cfg, p, x, zeros_q, rope=False)
     k, v = (jax.lax.dynamic_index_in_dim(cache[n], layer, 0, keepdims=False)
             for n in ("cross_k", "cross_v"))
-    o = _sdpa_naive(q, k, v, None, None, causal=False, mixed=cfg.attn_mixed,
-                    scale=cfg.attn_scale)
+    o = sdpa(q, k, v, None, None, False, cfg.attn_scale)
     return _out_proj(p, o)
 
 

@@ -127,8 +127,9 @@ def _residual(cfg, h, y):
 
 
 def _apply_layer(cfg, j: int, p: dict, h, *, positions, mode: str,
-                 cache: dict | None, pos, layer, context):
-    """One layer.  Returns (h, new_cache, aux).  In decode, ``cache``
+                 cache: dict | None, cache_len, pos, layer, context):
+    """One layer.  Returns (h, new_cache, aux).  In prefill the new cache
+    is this layer's, its K/V padded to ``cache_len``.  In decode, ``cache``
     holds this layer's leaves stacked over blocks and ``layer`` is the
     block index; the new cache is the stacked leaves, updated."""
     aux = _no_aux(mode)
@@ -143,7 +144,7 @@ def _apply_layer(cfg, j: int, p: dict, h, *, positions, mode: str,
             mix = attn.self_attention(cfg, p["attn"], normed, positions, rope=rope)
         elif mode == "prefill":
             mix, kv = attn.self_attention_prefill(
-                cfg, p["attn"], normed, positions, cache["k"].shape[1], rope=rope)
+                cfg, p["attn"], normed, positions, cache_len, rope=rope)
             new_cache.update(kv)
         else:  # decode
             mix, kv = attn.self_attention_decode(cfg, p["attn"], normed, cache,
@@ -200,17 +201,18 @@ def _apply_layer(cfg, j: int, p: dict, h, *, positions, mode: str,
 
 
 def apply_block(cfg, p: dict, h, *, positions, mode: str, cache: dict | None,
-                pos=None, layer=None, context=None):
+                cache_len=None, pos=None, layer=None, context=None):
     """Apply one block (list of layers).  Returns (h, new_cache, aux), aux
-    summed over the layers.  In decode ``cache`` is the whole stacked cache
-    and ``layer`` the block's index into it."""
+    summed over the layers.  In prefill ``cache_len`` is the length the K/V
+    cache is padded to; in decode ``cache`` is the whole stacked cache and
+    ``layer`` the block's index into it."""
     aux = _no_aux(mode)
     new_layers = []
     for j, lp in enumerate(p["layers"]):
         lcache = cache["layers"][j] if cache is not None else None
         h, nc, a = _apply_layer(cfg, j, lp, h, positions=positions, mode=mode,
-                                cache=lcache, pos=pos, layer=layer,
-                                context=context)
+                                cache=lcache, cache_len=cache_len, pos=pos,
+                                layer=layer, context=context)
         new_layers.append(nc)
         aux = aux + a
     return h, {"layers": new_layers}, aux

@@ -94,8 +94,7 @@ def dec_hidden(cfg, params, tokens, enc_out):
     return apply_norm(cfg, params["final_norm"], h)
 
 
-def dec_prefill(cfg, params, tokens, enc_out, cache_len: int,
-                cache_dtype=jnp.bfloat16):
+def dec_prefill(cfg, params, tokens, enc_out, cache_len: int):
     """Returns (h, cache).  Cache per layer: self {"k","v"} + {"cross_k","cross_v"}."""
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))

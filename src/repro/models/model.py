@@ -81,58 +81,9 @@ def _xent_full(cfg, params, h, targets):
     return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
-def _xent_chunked(cfg, params, h, targets):
-    """Streaming-logsumexp cross-entropy over vocab chunks: never materializes
-    the (B,S,V) logits tensor.  Beyond-paper memory optimization (hillclimb
-    lever ``cfg.xent_impl``)."""
-    emb = params["embed"]
-    W = emb["tok"] if cfg.tie_embeddings else emb["head"]      # (V,d) or (d,V)
-    Vp, d = cfg.padded_vocab, cfg.d_model
-    ck = cfg.xent_chunk
-    assert Vp % ck == 0, (Vp, ck)
-    n_chunks = Vp // ck
-    B, S, _ = h.shape
-    hf = h.astype(jnp.float32)
-    tgt = jnp.clip(targets, 0, Vp - 1)
-
-    def body(carry, i):
-        m, s, gold = carry
-        c0 = i * ck
-        if cfg.tie_embeddings:
-            Wc = jax.lax.dynamic_slice_in_dim(W, c0, ck, 0).astype(jnp.float32)
-            lg = jnp.einsum("bsd,vd->bsv", hf, Wc)
-        else:
-            Wc = jax.lax.dynamic_slice_in_dim(W, c0, ck, 1).astype(jnp.float32)
-            lg = jnp.einsum("bsd,dv->bsv", hf, Wc)
-        if cfg.logits_scaling != 1.0:
-            lg = lg / cfg.logits_scaling
-        col = c0 + jnp.arange(ck)
-        lg = jnp.where((col >= cfg.vocab_size)[None, None, :], -1e30, lg)
-        mc = jnp.max(lg, axis=-1)
-        m_new = jnp.maximum(m, mc)
-        s = s * jnp.exp(m - m_new) + jnp.sum(jnp.exp(lg - m_new[..., None]), axis=-1)
-        in_rng = (tgt >= c0) & (tgt < c0 + ck)
-        idx = jnp.clip(tgt - c0, 0, ck - 1)
-        g = jnp.take_along_axis(lg, idx[..., None], axis=-1)[..., 0]
-        gold = jnp.where(in_rng, g, gold)
-        return (m_new, s, gold), None
-
-    init = (jnp.full((B, S), -1e30, jnp.float32), jnp.zeros((B, S), jnp.float32),
-            jnp.zeros((B, S), jnp.float32))
-    (m, s, gold), _ = jax.lax.scan(body, init, jnp.arange(n_chunks),
-                                   unroll=True if cfg.unroll_blocks else 1)
-    lse = m + jnp.log(s)
-    nll = lse - gold
-    mask = (targets != IGNORE).astype(jnp.float32)
-    return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-
-
 def loss_fn(cfg, params, batch):
     h, aux = forward_hidden(cfg, params, batch)
-    if cfg.xent_impl == "chunked":
-        xent = _xent_chunked(cfg, params, h, batch["targets"])
-    else:
-        xent = _xent_full(cfg, params, h, batch["targets"])
+    xent = _xent_full(cfg, params, h, batch["targets"])
     loss = xent + aux
     return loss, {"loss": loss, "xent": xent, "aux": aux}
 
@@ -141,19 +92,17 @@ def loss_fn(cfg, params, batch):
 # Serving paths
 # ---------------------------------------------------------------------------
 
-def prefill(cfg, params, batch, cache_len: int, cache_dtype=jnp.bfloat16,
-            *, expert_counts: bool = False):
+def prefill(cfg, params, batch, cache_len: int, *,
+            expert_counts: bool = False):
     """Returns (last-token logits (B,1,V), cache[, MoE counts])."""
     counts = jnp.zeros((2,), jnp.int32)
     if cfg.family == "encdec":
         enc = ed.encode(cfg, params, batch["frames"])
-        h, cache = ed.dec_prefill(cfg, params, batch["tokens"], enc, cache_len,
-                                  cache_dtype)
+        h, cache = ed.dec_prefill(cfg, params, batch["tokens"], enc, cache_len)
     else:
         h, cache, counts = tf.lm_prefill(cfg, params, batch["tokens"],
                                          cache_len,
-                                         context=_context(cfg, batch),
-                                         cache_dtype=cache_dtype)
+                                         context=_context(cfg, batch))
     lg = logits_from_hidden(cfg, params, h[:, -1:])
     return (lg, cache, counts) if expert_counts else (lg, cache)
 

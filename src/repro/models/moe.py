@@ -7,18 +7,10 @@ per-expert ``capacity`` are dropped, and rows are scattered into an
 (..., E, C, d) buffer that feeds batched per-expert GEMMs — no (T, E, C)
 one-hot dispatch tensor is ever materialized.
 
-Two dispatch scopes, selected by ``cfg.moe_sharded_dispatch``:
-
-* ``False`` (baseline) — one GLOBAL dispatch group over all B*S tokens.
-  Under GSPMD with tokens sharded over `data` and experts over `model`, the
-  scatter into the global buffer resolves to an all-reduce of the whole
-  (E, C, d) buffer across `data` (measured: 15 TB/device for
-  moonshot×train_4k) — the paper-faithful naive baseline.
-* ``True`` — GShard-style *grouped* dispatch: every batch row is its own
-  dispatch group with local capacity, so buffer slots are owned by exactly
-  one data shard and the dispatch is communication-free by construction;
-  expert GEMMs run on (group→data, expert→model)-sharded buffers.  Capacity
-  dropping then acts per group (GShard's actual semantics).
+All B*S tokens form one dispatch group.  Under GSPMD with tokens sharded
+over `data` and experts over `model`, the scatter into that global buffer
+resolves to an all-reduce of the whole (E, C, d) buffer across `data`
+(15 TB/device measured in the moonshot x train_4k dry-run).
 
 Expert weights carry the "experts" logical axis and shard over the model mesh
 axis (expert parallelism).
@@ -67,17 +59,6 @@ def _capacity(cfg, n_tokens: int) -> int:
     return max(8, -(-c // 8) * 8)  # round up to 8, floor 8
 
 
-def _constrain(x, *entries):
-    """Best-effort with_sharding_constraint: a bare PartitionSpec resolves
-    against the ambient mesh context; outside one (CPU smoke paths) the call
-    raises and we fall back to a no-op."""
-    import jax.sharding as js
-    try:
-        return jax.lax.with_sharding_constraint(x, js.PartitionSpec(*entries))
-    except Exception:  # noqa: BLE001 — sharding hints must never break math
-        return x
-
-
 def apply_moe(cfg, p, x):
     """x: (B, S, d) -> (out (B, S, d), aux_loss scalar).  Training path
     over a layer that holds every expert: per-expert capacity, assignments
@@ -85,73 +66,56 @@ def apply_moe(cfg, p, x):
     m = cfg.moe
     B, S, d = x.shape
     E, k = m.num_experts, m.top_k
-    grouped = cfg.moe_sharded_dispatch
-    G = B if grouped else 1                   # dispatch groups
-    T = S if grouped else B * S               # tokens per group
-    xg = x.reshape(G, T, d)
+    T = B * S
+    xt = x.reshape(T, d)
 
     # --- routing (fp32) ----------------------------------------------------
-    logits = jnp.einsum("gtd,de->gte", xg.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)                    # (G,T,E)
-    top_p, top_e = jax.lax.top_k(probs, k)                     # (G,T,k)
+    logits = xt.astype(jnp.float32) @ p["router"]              # (T,E)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, k)                     # (T,k)
     top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
     # load-balancing aux loss (Switch), computed over ALL tokens
-    me = jnp.mean(probs, axis=(0, 1))                          # (E,)
+    me = jnp.mean(probs, axis=0)                               # (E,)
     assign = jnp.zeros((E,), jnp.float32).at[top_e.reshape(-1)].add(1.0)
-    fe = assign / (G * T * k)
+    fe = assign / (T * k)
     aux = m.router_aux_weight * E * jnp.sum(fe * me)
 
-    # --- capacity-bounded sort dispatch (vectorized over groups) -----------
+    # --- capacity-bounded sort dispatch ------------------------------------
     C = _capacity(cfg, T)
-    flat_e = top_e.reshape(G, T * k)                           # (G,TK)
-    sort_idx = jnp.argsort(flat_e, axis=-1, stable=True)
-    sorted_e = jnp.take_along_axis(flat_e, sort_idx, axis=-1)
-    counts = jnp.zeros((G, E), jnp.int32).at[
-        jnp.arange(G)[:, None], flat_e].add(1)
-    starts = jnp.concatenate(
-        [jnp.zeros((G, 1), jnp.int32), jnp.cumsum(counts, axis=-1)[:, :-1]],
-        axis=-1)                                               # (G,E)
-    pos_in_e = (jnp.arange(T * k, dtype=jnp.int32)[None]
-                - jnp.take_along_axis(starts, sorted_e, axis=-1))
+    flat_e = top_e.reshape(T * k)
+    sort_idx = jnp.argsort(flat_e, stable=True)
+    sorted_e = flat_e[sort_idx]
+    counts = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
+    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                              jnp.cumsum(counts)[:-1]])        # (E,)
+    pos_in_e = jnp.arange(T * k, dtype=jnp.int32) - starts[sorted_e]
     keep = pos_in_e < C
-    dest = sorted_e * C + jnp.where(keep, pos_in_e, 0)         # (G,TK)
-    src_tok = sort_idx // k                                    # (G,TK)
+    dest = sorted_e * C + jnp.where(keep, pos_in_e, 0)         # (TK,)
+    src_tok = sort_idx // k
 
     # dropped entries are zeroed and .add'ed at slot 0 of their expert, so
     # they cannot clobber a kept row (a .set with colliding indices would).
-    # NOTE: constraining rows/buf BEFORE the scatter was tried and strongly
-    # refuted (3.4x more collective traffic — see EXPERIMENTS.md §Perf
-    # moonshot iter-3); only the post-scatter constraint below helps.
-    rows = (jnp.take_along_axis(xg, src_tok[..., None], axis=1)
-            * keep[..., None].astype(xg.dtype))                # (G,TK,d)
-    buf = jnp.zeros((G, E * C, d), xg.dtype).at[
-        jnp.arange(G)[:, None], dest].add(rows)
-    buf = buf.reshape(G, E, C, d)
-    if grouped:
-        # groups -> data, experts -> model: the expert GEMMs below are local
-        buf = _constrain(buf, "data", "model", None, None)
+    # Constraining rows/buf to a sharding before the scatter was tried under
+    # GSPMD and moved 3.4x more collective traffic (moonshot dry-run).
+    rows = xt[src_tok] * keep[:, None].astype(xt.dtype)        # (TK,d)
+    buf = jnp.zeros((E * C, d), xt.dtype).at[dest].add(rows)
+    buf = buf.reshape(E, C, d)
 
-    # --- per-expert SwiGLU (batched GEMMs over group x expert) -------------
+    # --- per-expert SwiGLU (batched GEMMs over experts) --------------------
     dt = buf.dtype
-    g_ = jnp.einsum("gecd,edf->gecf", buf, p["w_gate"].astype(dt))
-    u_ = jnp.einsum("gecd,edf->gecf", buf, p["w_up"].astype(dt))
+    g_ = jnp.einsum("ecd,edf->ecf", buf, p["w_gate"].astype(dt))
+    u_ = jnp.einsum("ecd,edf->ecf", buf, p["w_up"].astype(dt))
     h = jax.nn.silu(g_) * u_
-    out_buf = jnp.einsum("gecf,efd->gecd", h, p["w_down"].astype(dt))
-    if grouped:
-        out_buf = _constrain(out_buf, "data", "model", None, None)
-    out_flat = out_buf.reshape(G, E * C, d)
+    out_flat = jnp.einsum("ecf,efd->ecd", h,
+                          p["w_down"].astype(dt)).reshape(E * C, d)
 
     # --- combine -------------------------------------------------------------
-    w = (jnp.take_along_axis(top_p.reshape(G, T * k), sort_idx, axis=-1)
-         * keep).astype(xg.dtype)                              # (G,TK)
-    contrib = jnp.take_along_axis(out_flat, dest[..., None], axis=1) \
-        * w[..., None]
-    y = jnp.zeros((G, T, d), xg.dtype).at[
-        jnp.arange(G)[:, None], src_tok].add(contrib)
+    w = (top_p.reshape(T * k)[sort_idx] * keep).astype(xt.dtype)
+    y = jnp.zeros((T, d), xt.dtype).at[src_tok].add(out_flat[dest] * w[:, None])
 
     if m.dense_residual:
-        y = y + apply_mlp(cfg, p["dense"], xg)
+        y = y + apply_mlp(cfg, p["dense"], xt)
     return y.reshape(B, S, d), aux
 
 

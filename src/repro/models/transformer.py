@@ -1,8 +1,7 @@
 """Decoder-only stack: scan-over-blocks with optional remat.
 
 Used by families dense / moe / ssm / hybrid / vlm.  Returns hidden states;
-unembedding and losses live in ``repro.models.model`` so the chunked-vocab
-cross-entropy can fuse with the projection.
+unembedding and losses live in ``repro.models.model``.
 """
 from __future__ import annotations
 
@@ -46,24 +45,20 @@ def lm_hidden(cfg, params, tokens, *, context=None):
     return h, jnp.sum(auxs)
 
 
-def lm_prefill(cfg, params, tokens, cache_len: int, *, context=None,
-               cache_dtype=jnp.bfloat16):
+def lm_prefill(cfg, params, tokens, cache_len: int, *, context=None):
     """Prefill: returns (h (B,S,d), stacked cache, the MoE layers' counts
     summed over layers: int32 (rows routed to held experts, held experts
     hit))."""
-    B, S = tokens.shape
     positions = _positions(tokens)
     h = embed_tokens(cfg, params["embed"], tokens)
-    init = stacked_cache(cfg, B, cache_len, cache_dtype)
 
-    def body(carry, xs):
-        hh = carry
-        bp, bc = xs
+    def body(hh, bp):
         hh, nc, counts = apply_block(cfg, bp, hh, positions=positions,
-                                     mode="prefill", cache=bc, context=context)
+                                     mode="prefill", cache=None,
+                                     cache_len=cache_len, context=context)
         return hh, (nc, counts)
 
-    h, (cache, counts) = jax.lax.scan(body, h, (params["blocks"], init),
+    h, (cache, counts) = jax.lax.scan(body, h, params["blocks"],
                                       unroll=True if cfg.unroll_blocks else 1)
     h = apply_norm(cfg, params["final_norm"], h)
     return h, cache, jnp.sum(counts, axis=0)

@@ -88,9 +88,7 @@ class ServingEngine:
                 batch = {"tokens": tokens}
                 if context is not None:
                     batch["vision"] = context
-                logits, cache = M.prefill(cfg, params, batch, self.max_len,
-                                          cache_dtype=jnp.float32)
-                return logits, cache
+                return M.prefill(cfg, params, batch, self.max_len)
 
             self._prefill_cache[plen] = jax.jit(_prefill)
         return self._prefill_cache[plen]
@@ -466,8 +464,7 @@ def generate_sequential(cfg, params, prompt: list, max_new_tokens: int,
     batch = {"tokens": tokens}
     if context is not None:
         batch["vision"] = context[None]
-    logits, cache = M.prefill(cfg, params, batch, max_len,
-                              cache_dtype=jnp.float32)
+    logits, cache = M.prefill(cfg, params, batch, max_len)
     out = [int(jnp.argmax(logits[0, -1, :cfg.vocab_size]))]
     pos = len(prompt)
     for _ in range(max_new_tokens - 1):

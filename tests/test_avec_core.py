@@ -160,8 +160,7 @@ def test_rpc_prefill_decode_matches_local():
     cfg, params, ex, rt, sess = _make_session()
     tok = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, cfg.vocab_size)
     remote = sess.call("prefill", {"tokens": np.asarray(tok)})
-    local_lg, _ = M.prefill(cfg, params, {"tokens": tok}, 32,
-                            cache_dtype=jnp.float32)
+    local_lg, _ = M.prefill(cfg, params, {"tokens": tok}, 32)
     np.testing.assert_allclose(remote["logits"], np.asarray(local_lg), atol=1e-4)
     # stateful decode continues at the destination
     out = sess.call("decode", {"tokens": np.asarray(tok[:, :1])})

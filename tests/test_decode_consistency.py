@@ -28,7 +28,7 @@ def test_prefill_decode_matches_forward(arch):
 
     pb = dict(batch)
     pb["tokens"] = tok[:, :P]
-    lg, cache = M.prefill(cfg, params, pb, Smax, cache_dtype=jnp.float32)
+    lg, cache = M.prefill(cfg, params, pb, Smax)
     errs = [float(np.max(np.abs(lg[:, 0] - full[:, P - 1])))]
     for t in range(P, S):
         db = {"tokens": tok[:, t:t + 1], "pos": jnp.asarray(t, jnp.int32)}
@@ -44,8 +44,7 @@ def test_per_row_positions_match_scalar():
     cfg = reduced(get_arch("granite-3-2b"))
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     tok = jax.random.randint(jax.random.PRNGKey(1), (2, 6), 0, cfg.vocab_size)
-    _, cache = M.prefill(cfg, params, {"tokens": tok}, 16,
-                         cache_dtype=jnp.float32)
+    _, cache = M.prefill(cfg, params, {"tokens": tok}, 16)
     nxt = tok[:, :1]
     l1, _ = M.decode_step(cfg, params, cache,
                           {"tokens": nxt, "pos": jnp.asarray(6)})

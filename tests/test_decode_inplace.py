@@ -38,9 +38,8 @@ def _nbytes(tree) -> int:
 def test_library_decode_is_bit_identical_to_the_undonated_step(arch):
     cfg, params, tok = _tiny(arch)
     lib = make_model_library(cfg, max_cache_len=MAX_LEN)
-    _, cache = jax.jit(lambda p, b: M.prefill(
-        cfg, p, b, MAX_LEN, cache_dtype=jnp.float32))(
-            params, {"tokens": tok[:, :PROMPT]})
+    _, cache = jax.jit(lambda p, b: M.prefill(cfg, p, b, MAX_LEN))(
+        params, {"tokens": tok[:, :PROMPT]})
     step = jax.jit(functools.partial(M.decode_step, cfg))
     state: dict = {}
     lib["prefill"](params, state, {"tokens": tok[:, :PROMPT]})
@@ -56,6 +55,33 @@ def test_library_decode_is_bit_identical_to_the_undonated_step(arch):
                     jax.tree_util.tree_leaves(cache)):
         assert a.dtype == b.dtype
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("arch", ["granite-3-2b", "mamba2-130m",
+                                  "granite-4.0-h-small"])
+def test_served_cache_is_in_the_compute_dtype(arch):
+    """The library's prefill serves K/V and conv state in the compute dtype
+    (bf16 here, where ``reduced`` alone computes in float32) and the SSM
+    state in float32; a decode gives back every leaf in its own dtype."""
+    cfg = with_overrides(reduced(get_arch(arch)), param_dtype="bfloat16",
+                         compute_dtype="bfloat16")
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    tok = np.asarray(jax.random.randint(jax.random.PRNGKey(1),
+                                        (1, PROMPT + 1), 0, cfg.vocab_size))
+    lib = make_model_library(cfg, max_cache_len=MAX_LEN)
+    state: dict = {}
+    lib["prefill"](params, state, {"tokens": tok[:, :PROMPT]})
+    want = {"k": jnp.bfloat16, "v": jnp.bfloat16, "conv": jnp.bfloat16,
+            "ssm": jnp.float32}
+    leaves = jax.tree_util.tree_flatten_with_path(state["cache"])[0]
+    assert {path[-1].key for path, _ in leaves} >= (
+        {"k", "v"} if arch == "granite-3-2b" else {"conv", "ssm"})
+    for path, leaf in leaves:
+        assert leaf.dtype == want[path[-1].key], (path, leaf.dtype)
+    given = [leaf.dtype for leaf in jax.tree_util.tree_leaves(state["cache"])]
+    lib["decode"](params, state, {"tokens": tok[:, PROMPT:]})
+    assert [leaf.dtype for leaf in
+            jax.tree_util.tree_leaves(state["cache"])] == given
 
 
 def test_a_copy_of_the_state_keeps_its_cache():

@@ -140,8 +140,7 @@ def test_decode_step_updates_the_granite_cache_in_place(one_chip):
 
     params = M.abstract_params(cfg)
     _, cache = jax.eval_shape(
-        lambda p, t: M.prefill(cfg, p, {"tokens": t}, 4096,
-                               cache_dtype=jnp.float32),
+        lambda p, t: M.prefill(cfg, p, {"tokens": t}, 4096),
         params, jax.ShapeDtypeStruct((1, 8), jnp.int32))
     batch = {"tokens": jax.ShapeDtypeStruct((1, 1), jnp.int32),
              "pos": jax.ShapeDtypeStruct((), jnp.int32)}
